@@ -27,40 +27,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import connectivity as CN
 from repro.core import staleness as SS
 from repro.core.utility import (RandomForestRegressor, featurize,
                                 transfer_report)
-from repro.fl.replan import ReplanService
+from repro.fl.replan import (ReplanService, calibrate_forest,
+                             rollout_histograms)
 
 S_MAX = 8
 DAYS = 0.25                    # 24 fifteen-minute windows per preset
 
 
-def _preset_hists(preset: str, s_max: int = S_MAX) -> np.ndarray:
-    """Per-window staleness histograms from protocol rollouts of `preset`
-    under a few periodic cadences (a spread of staleness mixes)."""
-    C = CN.connectivity_sets(CN.constellation_preset(preset), days=DAYS)
-    state = SS.bootstrap_state(C.shape[1])
-    hists = []
-    for period in (2, 3, 4, 6):
-        a = (np.arange(C.shape[0]) % period == period - 1).astype(np.int32)
-        _, _, infos = SS.simulate_window(
-            jnp.asarray(C), jnp.asarray(a), state, jnp.int32(0),
-            s_max=s_max, collect="hist")
-        hists.append(np.asarray(infos["hist"]))
-    return np.concatenate(hists).astype(np.float32)
-
-
 def calibrate(s_max: int = S_MAX) -> RandomForestRegressor:
-    """Fit û on flock191 rollouts against the staleness-discounted
-    aggregate-mass curve (the synthetic stand-in for eq.-12 targets)."""
-    H = _preset_hists("flock191", s_max)
-    X = featurize(H, 1.0)
-    s = np.arange(s_max + 1, dtype=np.float32)
-    y = ((H * (1.2 - 0.3 * s)).sum(1)
-         / np.maximum(H.sum(1), 1.0)).astype(np.float32)
-    return RandomForestRegressor(n_trees=30, max_depth=6, seed=0).fit(X, y)
+    """Fit û on flock191 rollouts (`repro.fl.replan.calibrate_forest`)."""
+    C = CN.connectivity_sets(CN.constellation_preset("flock191"), days=DAYS)
+    return calibrate_forest(C, s_max=s_max)
 
 
 def serve(preset: str, rf: RandomForestRegressor, *, I0: int = 12,
@@ -70,7 +52,8 @@ def serve(preset: str, rf: RandomForestRegressor, *, I0: int = 12,
     schedule's first action against the true protocol state."""
     C = CN.connectivity_sets(CN.constellation_preset(preset), days=DAYS)
     K = C.shape[1]
-    rep = transfer_report(rf, featurize(_preset_hists(preset), 1.0))
+    rep = transfer_report(rf, featurize(rollout_histograms(C, s_max=S_MAX),
+                                        1.0))
     print(f"{preset} (K={K}): in_envelope="
           f"{rep.get('in_envelope', 1.0):.2f}, "
           f"pred range [{rep['pred_min']:.3f}, {rep['pred_max']:.3f}]")
@@ -98,6 +81,7 @@ def serve(preset: str, rf: RandomForestRegressor, *, I0: int = 12,
 
 
 def main():
+    compile_cache.enable()
     rf = calibrate()
     print(f"calibrated on flock191: {rf.n_trees} trees, "
           f"{rf.n_features_} features\n")

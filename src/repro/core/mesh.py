@@ -1,19 +1,8 @@
 """Device-mesh layer for the simulation itself: the satellite axis of the
 Algorithm-1 protocol state sharded across devices.
 
-Everything up to PR 7 runs the protocol on one device; this module is the
-substrate that lets K >= 10^4 constellations fit and scale. It has three
-jobs:
+It has two jobs:
 
-  * **version compatibility** — `shard_map` / `AbstractMesh` moved and
-    renamed arguments across jax releases (``check_rep`` became
-    ``check_vma``; ``AbstractMesh`` switched from positional
-    ``(shape, axis_names)`` to ``(name, size)`` pairs and back). `shard_map`
-    and `abstract_mesh` here resolve the installed spelling once, so the
-    model-parallel stack (`repro.models.moe`, `repro.launch.steps`), the
-    protocol scans, and the sharding tests all run against pinned *and*
-    latest jax — these shims are what un-xfailed the seed-era sharding
-    tests.
   * **the simulation mesh** — `sim_mesh` builds the 1-D ``"sat"`` mesh the
     engine (`repro.fl.engine.SimulationEngine(mesh=...)`) and the eq.-13
     search (`repro.core.search.score_candidates(mesh=...)`) shard the
@@ -34,8 +23,6 @@ jobs:
 """
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Optional
 
 import jax
@@ -47,46 +34,13 @@ from repro.core import staleness as SS
 SAT_AXIS = "sat"
 
 
-# ---------------------------------------------------------------------------
-# version compatibility
-
-
-@functools.lru_cache(maxsize=None)
-def _resolve_shard_map():
-    """(shard_map callable, name of its replication-check kwarg)."""
-    try:
-        from jax import shard_map as fn          # jax >= 0.6 spelling
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as fn
-    params = inspect.signature(fn).parameters
-    kw = "check_vma" if "check_vma" in params else (
-        "check_rep" if "check_rep" in params else None)
-    return fn, kw
-
-
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """`jax.shard_map` under whichever name/signature the installed jax
-    ships. `check` maps onto ``check_vma`` (current) or ``check_rep``
-    (jax <= 0.4.x); it defaults to False because the protocol scans emit
-    psum-replicated outputs from inside `lax.scan`, which the static
-    replication checkers mis-track on some pinned versions — parity with
-    the single-device program is asserted by tests instead."""
-    fn, kw = _resolve_shard_map()
-    kwargs = {} if kw is None else {kw: check}
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
-
-
-def abstract_mesh(shape, axis_names):
-    """`jax.sharding.AbstractMesh` across signature generations: modern
-    jax takes positional ``(axis_sizes, axis_names)``, the 0.4.x line a
-    single tuple of ``(name, size)`` pairs. Spec-only computations (no
-    devices needed) build their mesh here."""
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(tuple(shape), tuple(axis_names))
-    except TypeError:
-        return AM(tuple(zip(axis_names, shape)))
+    """`jax.shard_map` with the replication check (``check_vma``) off by
+    default: the protocol scans emit psum-replicated outputs from inside
+    `lax.scan`, which the static checker mis-tracks — parity with the
+    single-device program is asserted by tests instead."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # ---------------------------------------------------------------------------

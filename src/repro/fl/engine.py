@@ -340,6 +340,15 @@ class SimResult:
             "replan_stats": self.replan_stats,
         }
 
+    def counters(self) -> dict:
+        """The integer protocol counters (no float result among them)."""
+        return {"global_updates": self.num_global_updates,
+                "aggregated_gradients": self.num_aggregated_gradients,
+                "idle_connections": self.idle_connections,
+                "total_connections": self.total_connections,
+                "staleness_hist": [int(c) for c in self.staleness_hist],
+                "windows_run": self.windows_run}
+
 
 @dataclass
 class EngineConfig:
@@ -1069,3 +1078,17 @@ class SimulationEngine:
             handler = getattr(cb, event, None)
             if handler is not None:
                 handler(self, *args)
+
+
+def protocol_mismatches(a: SimulationEngine,
+                        b: SimulationEngine) -> List[str]:
+    """Names of the protocol quantities in which two finished engines
+    differ: their last runs' `SimResult.counters`, the global version
+    `ig`, and the final `SatState` mirrors. Empty when the trajectories
+    are identical."""
+    ca, cb = a.result.counters(), b.result.counters()
+    out = [k for k in ca if ca[k] != cb[k]]
+    if a.ig != b.ig:
+        out.append("ig")
+    return out + [f for f in ("version", "pending", "buffered_base")
+                  if not np.array_equal(getattr(a, f), getattr(b, f))]

@@ -60,9 +60,10 @@ from repro.core.search import (event_positions, infer_n_range,
                                random_candidates, scan_candidates,
                                score_candidates, select_candidate,
                                step_candidates)
-from repro.core.utility import featurize_jnp, transfer_ready
+from repro.core.utility import (RandomForestRegressor, featurize,
+                                featurize_jnp, transfer_ready)
 
-__all__ = ["ReplanService"]
+__all__ = ["ReplanService", "calibrate_forest", "rollout_histograms"]
 
 
 class _Cache:
@@ -119,6 +120,36 @@ def _state_equal(a: SS.SatState, b: SS.SatState) -> bool:
     return len(la) == len(lb) and all(
         np.array_equal(np.asarray(x), np.asarray(y))
         for x, y in zip(la, lb))
+
+
+def rollout_histograms(C: np.ndarray, *, s_max: int = 8,
+                       periods=(2, 3, 4, 6)) -> np.ndarray:
+    """Per-window staleness histograms, (len(periods) * W, s_max + 1) f32,
+    from protocol rollouts of the (W, K) connectivity `C` under periodic
+    aggregation cadences (a spread of staleness mixes)."""
+    state = SS.bootstrap_state(C.shape[1])
+    hists = []
+    for period in periods:
+        a = (np.arange(C.shape[0]) % period == period - 1).astype(np.int32)
+        _, _, infos = SS.simulate_window(
+            jnp.asarray(C), jnp.asarray(a), state, jnp.int32(0),
+            s_max=s_max, collect="hist")
+        hists.append(np.asarray(infos["hist"]))
+    return np.concatenate(hists).astype(np.float32)
+
+
+def calibrate_forest(C: np.ndarray, *, s_max: int = 8, n_trees: int = 30,
+                     seed: int = 0) -> RandomForestRegressor:
+    """A transfer-ready utility forest for a `ReplanService` without
+    FedSpace's phase-1 training: fitted on `rollout_histograms(C)` against
+    the staleness-discounted aggregate-mass curve, a synthetic stand-in
+    for the eq.-12 targets."""
+    H = rollout_histograms(C, s_max=s_max)
+    s = np.arange(s_max + 1, dtype=np.float32)
+    y = ((H * (1.2 - 0.3 * s)).sum(1)
+         / np.maximum(H.sum(1), 1.0)).astype(np.float32)
+    return RandomForestRegressor(n_trees=n_trees, max_depth=6,
+                                 seed=seed).fit(featurize(H, 1.0), y)
 
 
 class ReplanService:

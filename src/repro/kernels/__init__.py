@@ -4,9 +4,10 @@
   rmsnorm/          RMSNorm over the model dim
   flash_attention/  causal / sliding-window flash attention (GQA)
 
-Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-public wrapper), and ref.py (pure-jnp oracle). On CPU they run with
-interpret=True; TPU is the compile target.
+Each kernel ships kernel.py (pl.pallas_call + BlockSpec, compiled unless
+called with interpret=True), ops.py (the public entry: the compiled kernel
+on TPU, the oracle elsewhere, differentiable on both), and ref.py
+(pure-jnp oracle).
 """
 import jax
 

@@ -25,6 +25,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# The kernel computes in f32, as its oracle does. At the default precision
+# Mosaic lowers an f32 dot to one bf16 MXU pass (an error near 1e-2 at
+# unit scale); HIGHEST makes it the multi-pass f32 product.
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -58,7 +62,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         k = k_ref[0, 0].astype(jnp.float32)              # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)              # (bk, hd)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ()))) * scale      # (bq, bk)
+            q, k, (((1,), (1,)), ((), ())), precision=F32,
+            preferred_element_type=jnp.float32) * scale  # (bq, bk)
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = kpos < seq_len
@@ -76,7 +81,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
+            p, v, (((1,), (0,)), ((), ())), precision=F32,
+            preferred_element_type=jnp.float32)
         m_scr[...] = m_new
         l_scr[...] = l_new
 
@@ -89,7 +95,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 512, bk: int = 512, interpret: bool = True):
+                    bq: int = 512, bk: int = 512, interpret: bool = False):
     """q: (B, H, Sq, hd); k, v: (B, K, Sk, hd); H % K == 0.
 
     window = 0 means unwindowed. Returns (B, H, Sq, hd).

@@ -13,6 +13,7 @@ from __future__ import annotations
 import argparse
 import json
 
+from repro import compile_cache
 from repro.fl.api import (AdapterConfig, ConstellationConfig, DatasetConfig,
                           FLExperiment, Federation, PartitionConfig,
                           SchedulerConfig)
@@ -68,6 +69,7 @@ def main():
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
+    compile_cache.enable()
     fed = Federation.from_experiment(build_experiment(args))
     if fed.scheduler_diag:
         print(f"utility regressor: {fed.scheduler_diag}")

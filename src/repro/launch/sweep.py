@@ -55,7 +55,9 @@ def main():
         arch, shape, mesh = combo
         cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
                "--shape", shape, "--mesh", mesh, "--out", args.out]
-        env = dict(os.environ)
+        # dry-runs compile for virtual CPU devices; they never need the
+        # chip, and parallel children must not contend for it
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         r = subprocess.run(cmd, capture_output=True, text=True, env=env,
                            timeout=1800)
         status = "ok" if r.returncode == 0 else "FAIL"

@@ -55,8 +55,9 @@ def test_quantization_error_bounded(n, k_frac):
 @given(st.integers(4, 150), st.floats(0.05, 1.0), st.integers(0, 10_000))
 def test_topk_keeps_exact_index_set(n, k_frac, seed):
     """With distinct magnitudes the kept index set is exactly the top-k by
-    |value|, and the dequantization error on kept entries is <= scale/2
-    (round-to-nearest)."""
+    |value|, each kept value is quantized to its nearest int8 level
+    (round-to-nearest of the float32 quotient v / scale), and `decompress`
+    returns exactly level * scale in float32."""
     rng = np.random.default_rng(seed)
     mags = rng.permutation(np.arange(1, n + 1)).astype(np.float32)
     vals = mags * rng.choice([-1.0, 1.0], n).astype(np.float32)
@@ -68,10 +69,11 @@ def test_topk_keeps_exact_index_set(n, k_frac, seed):
     assert set(np.asarray(leaf.indices).tolist()) == expect
     assert leaf.values.shape == (k,)
     assert b_c == k * 5 and b_r == n * 4
-    scale = float(leaf.scale)
-    deq = np.asarray(leaf.values, np.float32) * scale
-    err = np.abs(deq - vals[np.asarray(leaf.indices)])
-    assert err.max() <= scale / 2 + 1e-6
+    idx, q = np.asarray(leaf.indices), np.asarray(leaf.values)
+    scale = np.float32(leaf.scale)
+    np.testing.assert_array_equal(q, np.round(vals[idx] / scale))
+    deq = np.asarray(decompress(comp)["w"])[idx]
+    np.testing.assert_array_equal(deq, q.astype(np.float32) * scale)
 
 
 @settings(max_examples=15, deadline=None)

@@ -24,7 +24,8 @@ from repro.fl.api import (AdapterConfig, ConstellationConfig, DatasetConfig,
 from repro.fl.callbacks import (Callback, EarlyStopCallback,
                                 JsonlMetricsCallback)
 from repro.fl.client import make_client_update
-from repro.fl.engine import EngineConfig, SimulationEngine
+from repro.fl.engine import (EngineConfig, SimulationEngine,
+                             protocol_mismatches)
 from repro.fl.registry import (Registry, SCHEDULERS, register_scheduler)
 from repro.fl.simulation import run_simulation
 
@@ -124,9 +125,16 @@ def test_engine_matches_legacy_trajectory(tiny_world, scheme, kw):
                                  eval_every=16, max_windows=64)
     new = run_simulation(C, adapter, make_scheduler(scheme, **kw),
                          eval_every=16, max_windows=64)
-    assert new.summary() == ref.summary()
-    assert new.accuracy == ref.accuracy
-    assert new.val_loss == ref.val_loss
+    # Integer protocol counters are exact. Floats get a tolerance: the
+    # engine trains buffered satellites in one vmapped program, the legacy
+    # loop one jitted call per satellite, and the two XLA programs may
+    # round their reductions differently in the last ulp (jax 0.9 CPU
+    # does). One eval sample of 200 is 0.005 accuracy.
+    floats = ("final_acc", "best_acc")
+    strip = lambda d: {k: v for k, v in d.items() if k not in floats}
+    assert strip(new.summary()) == strip(ref.summary())
+    np.testing.assert_allclose(new.accuracy, ref.accuracy, atol=0.005)
+    np.testing.assert_allclose(new.val_loss, ref.val_loss, rtol=1e-5)
     assert new.eval_windows == ref.eval_windows
     assert new.windows_run == ref.windows_run
 
@@ -146,6 +154,24 @@ def test_engine_overridable_step(tiny_world):
     res = eng.run()
     assert res.num_global_updates > 0
     assert eng.version[0] == 0          # never downloaded a newer model
+
+
+def test_protocol_mismatches_names_what_differs(tiny_world):
+    """Two runs of one configuration match in every protocol quantity; a
+    different buffer size shows up in the counters and the final state."""
+    C, adapter = tiny_world
+
+    def run(M):
+        eng = SimulationEngine(C, adapter, make_scheduler("fedbuff", M=M),
+                               EngineConfig(eval_every=16, max_windows=48))
+        eng.run()
+        return eng
+
+    a = run(4)
+    assert protocol_mismatches(a, run(4)) == []
+    diff = protocol_mismatches(a, run(2))
+    assert {"global_updates", "ig", "version"} <= set(diff)
+    assert a.result.counters()["windows_run"] == 48
 
 
 # ---------------------------------------------------------------------------

@@ -190,3 +190,73 @@ def test_ops_interpret_true_close_to_oracle(key):
                                      jnp.moveaxis(vv, 2, 1), causal=True),
                        1, 2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# reverse mode through the kernel paths: a custom VJP whose backward pass is
+# the oracle's, so gradients match `jax.grad` of the oracle up to the
+# forward kernel's rounding
+
+
+def test_rmsnorm_kernel_path_grad_matches_oracle(key):
+    from repro.kernels.rmsnorm.ops import rmsnorm as rmsnorm_op
+    x = jax.random.normal(key, (16, 8, 32))
+    s = jax.random.normal(jax.random.fold_in(key, 1), (32,))
+    r = jax.random.normal(jax.random.fold_in(key, 2), x.shape)
+
+    def loss(fn):
+        return lambda x, s: jnp.sum(jnp.sin(fn(x, s)) * r)
+
+    got = jax.grad(loss(lambda x, s: rmsnorm_op(x, s, interpret=True)),
+                   argnums=(0, 1))(x, s)
+    ref = jax.grad(loss(rmsnorm_ref), argnums=(0, 1))(x, s)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("causal,window", [(True, 0), (False, 0), (True, 3)])
+def test_flash_kernel_path_grad_matches_oracle(key, causal, window):
+    from repro.kernels.flash_attention.ops import flash_attention_bshd
+    B, H, K, S, hd = 2, 4, 2, 8, 8
+    q = jax.random.normal(key, (B, S, H, hd))
+    kk = jax.random.normal(jax.random.fold_in(key, 1), (B, S, K, hd))
+    vv = jax.random.normal(jax.random.fold_in(key, 2), (B, S, K, hd))
+    r = jax.random.normal(jax.random.fold_in(key, 3), q.shape)
+
+    def ref_bshd(q, k, v):
+        t = lambda a: jnp.moveaxis(a, 2, 1)
+        return t(attention_ref(t(q), t(k), t(v), causal=causal,
+                               window=window))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(jnp.tanh(fn(q, k, v)) * r)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention_bshd(
+        q, k, v, causal=causal, window=window, bq=S, bk=S,
+        interpret=True)), argnums=(0, 1, 2))(q, kk, vv)
+    ref = jax.grad(loss(ref_bshd), argnums=(0, 1, 2))(q, kk, vv)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_kernel_path_grad_under_vmap_and_scan(key):
+    """The client update's shape of use: grad inside a scan over local
+    steps, vmapped over satellites."""
+    from repro.kernels.rmsnorm.ops import rmsnorm as rmsnorm_op
+    x = jax.random.normal(key, (3, 4, 8, 32))      # (M, steps, rows, D)
+    s0 = jnp.ones((32,))
+
+    def update(fn):
+        def one(xs):
+            def body(s, x):
+                g = jax.grad(lambda s: jnp.sum(fn(x, s) ** 2))(s)
+                return s - 0.01 * g, None
+            return jax.lax.scan(body, s0, xs)[0]
+        return jax.jit(jax.vmap(one))
+
+    got = update(lambda x, s: rmsnorm_op(x, s, interpret=True))(x)
+    ref = update(rmsnorm_ref)(x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)

@@ -14,7 +14,8 @@ from repro.core.search import (random_candidates, scan_candidates,
 from repro.core.utility import (MLPRegressor, RandomForestRegressor,
                                 featurize, n_features, transfer_ready,
                                 transfer_report)
-from repro.fl.replan import ReplanService
+from repro.fl.replan import (ReplanService, calibrate_forest,
+                             rollout_histograms)
 
 S_MAX = 8
 
@@ -215,6 +216,21 @@ def test_transfer_ready_gatekeeps_service():
     rf.n_features_ = 99                     # fitted at a different s_max
     with pytest.raises(ValueError, match="transfer-ready"):
         ReplanService(rf)
+
+
+def test_calibrated_forest_serves_the_service():
+    """`calibrate_forest` fits on one histogram row per window and
+    cadence, and its forest passes the service's transfer gate."""
+    C, state = _world(K=24, T=16)
+    H = rollout_histograms(C, s_max=S_MAX)
+    assert H.shape == (4 * 16, S_MAX + 1)
+    assert H.sum() > 0
+    rf = calibrate_forest(C, s_max=S_MAX, n_trees=4)
+    assert transfer_ready(rf, s_max=S_MAX)
+    svc = ReplanService(rf, I0=8, num_candidates=64, s_max=S_MAX, seed=0)
+    plan = svc.replan(0, C[:8], state, 0, 1.0,
+                      rng=np.random.default_rng(0))
+    assert plan.shape == (8,) and svc.last_mode == "full"
 
 
 # ---------------------------------------------------------------------------

@@ -19,11 +19,9 @@ from repro.optim import adamw_init
 ARCHS = [a for a in list_configs() if a != "densenet-fl"]
 
 def _fake_mesh():
-    """Abstract 16x16 mesh for spec computation only (no devices needed) —
-    `repro.core.mesh.abstract_mesh` bridges the AbstractMesh signature
-    change across jax versions."""
-    from repro.core.mesh import abstract_mesh
-    return abstract_mesh((16, 16), ("data", "model"))
+    """Abstract 16x16 mesh for spec computation only (no devices
+    needed)."""
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCHS)
