@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import mesh as MM
 from repro.core import staleness as SS
 from repro.core.utility import featurize, featurize_jnp
@@ -286,32 +287,41 @@ def scan_candidates(candidates: np.ndarray, C_window: np.ndarray,
     cands = np.asarray(candidates)
     R, I0 = cands.shape
     K = C_window.shape[1]
-    if link is not None:
-        link = SS.LinkGate(jnp.asarray(np.asarray(link.grant), jnp.int32),
-                           jnp.int32(link.need_up), jnp.int32(link.need_dn))
-    idx, mask = event_positions(cands)
-    Cw = jnp.asarray(np.asarray(C_window, bool))
-    st, igd = _narrow_state(state, int(ig), I0)
     if chunk_rows is None:
         chunk_rows = max(256, (64 << 20) // max(I0 * K, 1))
-    scores = np.empty(R, np.float32)
-    win_util = np.zeros((R, I0), np.float32)
-    end_states, end_igs = [], []
-    predict_device = regressor.predict_device
-    for c0 in range(0, R, chunk_rows):
-        rows = slice(c0, min(c0 + chunk_rows, R))
-        marks, fstate, fig = _simulate_marks_state(
-            Cw, jnp.asarray(cands[rows]), st, igd, link, s_max=s_max)
-        feats = _event_features(marks, jnp.asarray(idx[rows]),
-                                jnp.float32(status), s_max=s_max)
-        util = predict_device(feats).reshape(-1, idx.shape[1])
-        masked = util * jnp.asarray(mask[rows], jnp.float32)
-        scores[rows] = np.asarray(masked.sum(axis=1))
-        np.put_along_axis(win_util[rows], idx[rows], np.asarray(masked),
-                          axis=1)
-        end_states.append(jax.tree.map(np.asarray, fstate))
-        end_igs.append(np.asarray(fig))
-    end_state = jax.tree.map(lambda *xs: np.concatenate(xs), *end_states)
+    with tracing.span("search.scan", rows=R, chunks=-(-R // chunk_rows)):
+        if link is not None:
+            link = SS.LinkGate(
+                jnp.asarray(np.asarray(link.grant), jnp.int32),
+                jnp.int32(link.need_up), jnp.int32(link.need_dn))
+        idx, mask = event_positions(cands)
+        Cw = jnp.asarray(np.asarray(C_window, bool))
+        st, igd = _narrow_state(state, int(ig), I0)
+        scores = np.empty(R, np.float32)
+        win_util = np.zeros((R, I0), np.float32)
+        end_states, end_igs = [], []
+        predict_device = regressor.predict_device
+        for c0 in range(0, R, chunk_rows):
+            rows = slice(c0, min(c0 + chunk_rows, R))
+            # dispatch the chunk's programs; the wait for them lands in
+            # the host reads that follow
+            with tracing.span("search.chunk", rows=rows.stop - c0):
+                marks, fstate, fig = _simulate_marks_state(
+                    Cw, jnp.asarray(cands[rows]), st, igd, link,
+                    s_max=s_max)
+                feats = _event_features(marks, jnp.asarray(idx[rows]),
+                                        jnp.float32(status), s_max=s_max)
+                util = predict_device(feats).reshape(-1, idx.shape[1])
+                masked = util * jnp.asarray(mask[rows], jnp.float32)
+                total = masked.sum(axis=1)
+            with tracing.span("search.fetch"):
+                scores[rows] = np.asarray(total)
+                np.put_along_axis(win_util[rows], idx[rows],
+                                  np.asarray(masked), axis=1)
+                end_states.append(jax.tree.map(np.asarray, fstate))
+                end_igs.append(np.asarray(fig))
+        end_state = jax.tree.map(lambda *xs: np.concatenate(xs),
+                                 *end_states)
     return scores, {"win_util": win_util, "end_state": end_state,
                     "end_ig": np.concatenate(end_igs),
                     "state_dtype": np.dtype(np.int16)

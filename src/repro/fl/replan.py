@@ -46,6 +46,10 @@ serves starlink40/120/400/1000 unchanged. `examples/serve_replan.py`
 wraps the service in a persistent-jit server loop (the
 `examples/serve_decode.py` pattern): connectivity columns stream in,
 replan requests are answered without recompilation.
+
+Each step of a request is a `repro.tracing` span (`replan.request` and
+its children), recorded while a profiler session captures; the span tree
+and its counts are in `docs/replanning.md` (Tracing).
 """
 from __future__ import annotations
 
@@ -55,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import staleness as SS
 from repro.core.search import (event_positions, infer_n_range,
                                random_candidates, scan_candidates,
@@ -235,15 +240,18 @@ class ReplanService:
             return
         conn, gate = c.pending
         S = int(c.end_ig.shape[0])
-        sel = np.concatenate([np.arange(S),
-                              np.zeros(_bucket(S) - S, np.int64)])
-        _, st, g = step_candidates(
-            jax.tree.map(jnp.asarray, _rows(c.end_state, sel)),
-            jnp.asarray(c.end_ig[sel]), jnp.asarray(conn),
-            jnp.asarray(c.cands[sel, -1]), gate, s_max=self.s_max)
-        c.end_state = jax.tree.map(lambda x: np.asarray(x)[:S], st)
-        c.end_ig = np.asarray(g)[:S]
-        c.pending = None
+        # `window` is the request this advance prepares: the next one
+        with tracing.span("replan.maintain", window=c.window + 1, rows=S,
+                          bucket=_bucket(S)):
+            sel = np.concatenate([np.arange(S),
+                                  np.zeros(_bucket(S) - S, np.int64)])
+            _, st, g = step_candidates(
+                jax.tree.map(jnp.asarray, _rows(c.end_state, sel)),
+                jnp.asarray(c.end_ig[sel]), jnp.asarray(conn),
+                jnp.asarray(c.cands[sel, -1]), gate, s_max=self.s_max)
+            c.end_state = jax.tree.map(lambda x: np.asarray(x)[:S], st)
+            c.end_ig = np.asarray(g)[:S]
+            c.pending = None
 
     # -- request path -------------------------------------------------------
 
@@ -269,20 +277,24 @@ class ReplanService:
         the delta path; anything else falls back to a full rescan (see
         the module docstring for the invalidation table).
         """
-        C_window = np.asarray(C_window, bool)
-        self.maintain()
-        reason = self._delta_blocker(window, C_window, state, ig, status,
-                                     link)
-        if reason is None:
-            self.last_mode, self.last_reason = "delta", None
-            self.stats["delta"] += 1
-            return self._delta(window, C_window, state, ig, status, link)
-        if self._cache is not None and reason != "cold":
-            self.invalidate(reason)
-        self.last_mode, self.last_reason = "full", reason
-        self.stats["full"] += 1
-        return self._full(window, C_window, state, ig, status, link, rng,
-                          n_min, n_max)
+        with tracing.span("replan.request", window=window):
+            C_window = np.asarray(C_window, bool)
+            self.maintain()
+            with tracing.span("replan.check", window=window):
+                reason = self._delta_blocker(window, C_window, state, ig,
+                                             status, link)
+            if reason is None:
+                self.last_mode, self.last_reason = "delta", None
+                self.stats["delta"] += 1
+                return self._delta(window, C_window, state, ig, status,
+                                   link)
+            if self._cache is not None and reason != "cold":
+                self.invalidate(reason)
+            self.last_mode, self.last_reason = "full", reason
+            self.stats["full"] += 1
+            with tracing.span("replan.full", window=window, reason=reason):
+                return self._full(window, C_window, state, ig, status,
+                                  link, rng, n_min, n_max)
 
     # -- full plan ----------------------------------------------------------
 
@@ -292,14 +304,16 @@ class ReplanService:
         rng = rng if rng is not None else self._rng
         n_min = n_min if n_min is not None else self.n_min
         n_max = n_max if n_max is not None else self.n_max
-        if n_min is None or n_max is None:
-            inf_min, inf_max = infer_n_range(
-                self.regressor, float(Cw.mean(axis=1).sum()) / I0 * K,
-                I0, status, s_max=self.s_max, K=K)
-            n_min = n_min if n_min is not None else inf_min
-            n_max = n_max if n_max is not None else inf_max
-        cands = random_candidates(rng, I0, n_min, n_max,
-                                  self.num_candidates)
+        with tracing.span("replan.full.draw", window=window,
+                          candidates=self.num_candidates):
+            if n_min is None or n_max is None:
+                inf_min, inf_max = infer_n_range(
+                    self.regressor, float(Cw.mean(axis=1).sum()) / I0 * K,
+                    I0, status, s_max=self.s_max, K=K)
+                n_min = n_min if n_min is not None else inf_min
+                n_max = n_max if n_max is not None else inf_max
+            cands = random_candidates(rng, I0, n_min, n_max,
+                                      self.num_candidates)
         if self.mesh is not None:
             scores = score_candidates(cands, Cw, state, ig, self.regressor,
                                       status, s_max=self.s_max, link=link,
@@ -309,20 +323,21 @@ class ReplanService:
             scores, art = scan_candidates(cands, Cw, state, ig,
                                           self.regressor, status,
                                           s_max=self.s_max, link=link)
-        w = select_candidate(cands, scores)
-        if art is not None:
-            self._cache = _Cache(
-                window=window, cands=cands, Cw=Cw.copy(),
-                grant=None if link is None
-                else np.asarray(link.grant, np.int32).copy(),
-                need_up=0 if link is None else int(link.need_up),
-                need_dn=0 if link is None else int(link.need_dn),
-                win_util=art["win_util"], end_state=art["end_state"],
-                end_ig=art["end_ig"], state_dtype=art["state_dtype"],
-                pre_state=_np_state(state), pre_ig=int(ig),
-                winner_bit=int(cands[w, 0]), status=float(status),
-                density=float(cands.mean()), n_max=n_max)
-        return cands[w].copy()
+        with tracing.span("replan.select", window=window):
+            w = select_candidate(cands, scores)
+            if art is not None:
+                self._cache = _Cache(
+                    window=window, cands=cands, Cw=Cw.copy(),
+                    grant=None if link is None
+                    else np.asarray(link.grant, np.int32).copy(),
+                    need_up=0 if link is None else int(link.need_up),
+                    need_dn=0 if link is None else int(link.need_dn),
+                    win_util=art["win_util"], end_state=art["end_state"],
+                    end_ig=art["end_ig"], state_dtype=art["state_dtype"],
+                    pre_state=_np_state(state), pre_ig=int(ig),
+                    winner_bit=int(cands[w, 0]), status=float(status),
+                    density=float(cands.mean()), n_max=n_max)
+            return cands[w].copy()
 
     # -- delta path ---------------------------------------------------------
 
@@ -393,68 +408,85 @@ class ReplanService:
     def _delta(self, window, Cw, state, ig, status, link):
         c = self._cache
         keep = c.cands[:, 0] == c.winner_bit
-        base = c.cands[keep]
-        S = base.shape[0]
-        # extend every survivor with a drawn bit for the revealed window
-        # (service rng; capped so no candidate exceeds the draw-time n_max)
-        n_now = base[:, 1:].sum(axis=1)
-        draw = (self._rng.random(S) < c.density).astype(np.int32)
-        new_bits = np.where(n_now < c.n_max, draw, 0).astype(np.int32)
-        cands = np.concatenate([base[:, 1:], new_bits[:, None]], axis=1)
-        win_util = np.concatenate(
-            [c.win_util[keep, 1:], np.zeros((S, 1), np.float32)], axis=1)
-        end_state = _rows(c.end_state, keep)
-        end_ig = c.end_ig[keep]
-        # simulate ONLY the newly revealed window, only for candidates
-        # that scheduled it — same marks→hist→featurize→predict pipeline
-        # as the full scan, from the cached per-candidate frontier
-        conn_new = Cw[-1]
-        gate_new = self._gate(None if link is None
-                              else np.asarray(link.grant, np.int32)[-1],
-                              c.need_up, c.need_dn)
-        rows1 = np.flatnonzero(new_bits == 1)
-        if rows1.size:
-            m = rows1.size
-            sel = np.concatenate(
-                [rows1, np.full(_bucket(m) - m, rows1[0], np.int64)])
-            marks, _, _ = step_candidates(
-                jax.tree.map(jnp.asarray, _rows(end_state, sel)),
-                jnp.asarray(end_ig[sel]), jnp.asarray(conn_new),
-                jnp.asarray(new_bits[sel]), gate_new, s_max=self.s_max)
-            hists = SS.hist_from_marks(marks, s_max=self.s_max,
-                                       dtype=jnp.int16)
-            util = self.regressor.predict_device(
-                featurize_jnp(hists, jnp.float32(status)))
-            win_util[rows1, -1] = np.asarray(util)[:m]
-        # re-reduce at the same per-row (n_cap,) shape a full rescan would
-        # use, so the masked sum is bit-identical to score_candidates.
-        # Rows are bucket-padded with zeros (per-row sums unaffected) so
-        # the eager device reduction reuses a handful of compiled shapes
-        # instead of recompiling for every survivor count.
-        idx, mask = event_positions(cands)
-        util_ev = np.take_along_axis(win_util, idx, axis=1)
-        pad = _bucket(S) - S
-        if pad:
-            util_ev = np.concatenate(
-                [util_ev, np.zeros((pad, util_ev.shape[1]), np.float32)])
-            mask = np.concatenate(
-                [mask, np.zeros((pad, mask.shape[1]), mask.dtype)])
-        scores = np.asarray((jnp.asarray(util_ev)
-                             * jnp.asarray(mask, jnp.float32))
-                            .sum(axis=1))[:S]
-        w = select_candidate(cands, scores)
-        # roll the cache forward; the frontier advance is deferred to
-        # maintain() so it stays off the answer path
-        c.window = window
-        c.cands = cands
-        c.Cw = Cw.copy()
-        if link is not None:
-            c.grant = np.asarray(link.grant, np.int32).copy()
-        c.win_util = win_util
-        c.end_state = end_state
-        c.end_ig = end_ig
-        c.pre_state = _np_state(state)
-        c.pre_ig = int(ig)
-        c.winner_bit = int(cands[w, 0])
-        c.pending = (conn_new.copy(), gate_new)
-        return cands[w].copy()
+        S = int(np.count_nonzero(keep))
+        with tracing.span("replan.delta", window=window, survivors=S,
+                          bucket=_bucket(S)):
+            with tracing.span("replan.delta.extend", window=window):
+                base = c.cands[keep]
+                # extend every survivor with a drawn bit for the revealed
+                # window (service rng; capped so no candidate exceeds the
+                # draw-time n_max)
+                n_now = base[:, 1:].sum(axis=1)
+                draw = (self._rng.random(S) < c.density).astype(np.int32)
+                new_bits = np.where(n_now < c.n_max, draw, 0).astype(
+                    np.int32)
+                cands = np.concatenate([base[:, 1:], new_bits[:, None]],
+                                       axis=1)
+                win_util = np.concatenate(
+                    [c.win_util[keep, 1:], np.zeros((S, 1), np.float32)],
+                    axis=1)
+                end_state = _rows(c.end_state, keep)
+                end_ig = c.end_ig[keep]
+                conn_new = Cw[-1]
+                gate_new = self._gate(
+                    None if link is None
+                    else np.asarray(link.grant, np.int32)[-1],
+                    c.need_up, c.need_dn)
+                rows1 = np.flatnonzero(new_bits == 1)
+            # simulate ONLY the newly revealed window, only for candidates
+            # that scheduled it — same marks→hist→featurize→predict
+            # pipeline as the full scan, from the cached frontier
+            if rows1.size:
+                m = rows1.size
+                with tracing.span("replan.delta.score", window=window,
+                                  scheduled=m, bucket=_bucket(m)):
+                    sel = np.concatenate(
+                        [rows1, np.full(_bucket(m) - m, rows1[0], np.int64)])
+                    marks, _, _ = step_candidates(
+                        jax.tree.map(jnp.asarray, _rows(end_state, sel)),
+                        jnp.asarray(end_ig[sel]), jnp.asarray(conn_new),
+                        jnp.asarray(new_bits[sel]), gate_new,
+                        s_max=self.s_max)
+                    hists = SS.hist_from_marks(marks, s_max=self.s_max,
+                                               dtype=jnp.int16)
+                    util = self.regressor.predict_device(
+                        featurize_jnp(hists, jnp.float32(status)))
+                    win_util[rows1, -1] = np.asarray(util)[:m]
+            # re-reduce at the same per-row (n_cap,) shape a full rescan
+            # would use, so the masked sum is bit-identical to
+            # score_candidates. Rows are bucket-padded with zeros (per-row
+            # sums unaffected) so the eager device reduction reuses a
+            # handful of compiled shapes instead of recompiling for every
+            # survivor count.
+            with tracing.span("replan.delta.reduce", window=window,
+                              rows=_bucket(S)) as sp:
+                idx, mask = event_positions(cands)
+                sp.set_metadata(width=idx.shape[1])
+                util_ev = np.take_along_axis(win_util, idx, axis=1)
+                pad = _bucket(S) - S
+                if pad:
+                    util_ev = np.concatenate(
+                        [util_ev,
+                         np.zeros((pad, util_ev.shape[1]), np.float32)])
+                    mask = np.concatenate(
+                        [mask, np.zeros((pad, mask.shape[1]), mask.dtype)])
+                scores = np.asarray((jnp.asarray(util_ev)
+                                     * jnp.asarray(mask, jnp.float32))
+                                    .sum(axis=1))[:S]
+            with tracing.span("replan.select", window=window):
+                w = select_candidate(cands, scores)
+                # roll the cache forward; the frontier advance is deferred
+                # to maintain() so it stays off the answer path
+                c.window = window
+                c.cands = cands
+                c.Cw = Cw.copy()
+                if link is not None:
+                    c.grant = np.asarray(link.grant, np.int32).copy()
+                c.win_util = win_util
+                c.end_state = end_state
+                c.end_ig = end_ig
+                c.pre_state = _np_state(state)
+                c.pre_ig = int(ig)
+                c.winner_bit = int(cands[w, 0])
+                c.pending = (conn_new.copy(), gate_new)
+                return cands[w].copy()
