@@ -18,7 +18,7 @@ import numpy as np
 from repro import tracing
 from repro.core import mesh as MM
 from repro.core import staleness as SS
-from repro.core.utility import featurize, featurize_jnp
+from repro.core.utility import featurize, featurize_jnp, forest_dense
 
 
 def random_candidates(rng: np.random.Generator, I0: int, n_min: int,
@@ -289,7 +289,8 @@ def scan_candidates(candidates: np.ndarray, C_window: np.ndarray,
     K = C_window.shape[1]
     if chunk_rows is None:
         chunk_rows = max(256, (64 << 20) // max(I0 * K, 1))
-    with tracing.span("search.scan", rows=R, chunks=-(-R // chunk_rows)):
+    with tracing.span("search.scan", rows=R, chunks=-(-R // chunk_rows),
+                      forest_dense=forest_dense(regressor)):
         if link is not None:
             link = SS.LinkGate(
                 jnp.asarray(np.asarray(link.grant), jnp.int32),

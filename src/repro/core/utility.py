@@ -149,9 +149,10 @@ class _Node:
 @dataclass(frozen=True)
 class ForestArrays:
     """Structure-of-arrays view of a fitted forest: (n_trees, max_nodes)
-    per-node fields, leaf-padded so every tree shares one node axis.
-    `feature < 0` marks a leaf; leaf left/right self-loop to node 0 so the
-    level-wise traversal below is branch-free."""
+    per-node fields in the fit's depth-first order, leaf-padded so every
+    tree shares one node axis. `feature < 0` marks a leaf; leaf left/right
+    self-loop to node 0 so the level-wise host traversal is branch-free.
+    The device path re-lays it as complete heaps (`forest_to_heap`)."""
     feature: np.ndarray    # (T, M) int32, -1 at leaves / padding
     thresh: np.ndarray     # (T, M) f32
     left: np.ndarray       # (T, M) int32
@@ -179,6 +180,36 @@ def forest_to_arrays(trees: List[List[_Node]], max_depth: int
     return ForestArrays(feature, thresh, left, right, value, max_depth)
 
 
+def forest_to_heap(fa: ForestArrays):
+    """Re-lay every tree as a complete heap of `fa.depth` levels: inner
+    node h has children 2h+1 and 2h+2. Returns feature (T, 2^D - 1) int32,
+    thresh (T, 2^D - 1) f32 and leaf values (T, 2^D) f32. A leaf met above
+    the last level fills every heap leaf under it with its value; the
+    padded inner nodes under it keep feature 0 and threshold +inf, and
+    since both their children carry the same value the direction taken
+    there cannot change the result. A node reached at depth D serves its
+    own value, as the level-wise traversal that stops after D levels does.
+    Takes depth-first (unbalanced) and heap-ordered forests alike."""
+    T, D = fa.feature.shape[0], fa.depth
+    feature = np.zeros((T, 2 ** D - 1), np.int32)
+    thresh = np.full((T, 2 ** D - 1), np.inf, np.float32)
+    leaf = np.zeros((T, 2 ** D), np.float32)
+    for t in range(T):
+        stack = [(0, 0, 0)]                  # (node, heap position, level)
+        while stack:
+            n, h, d = stack.pop()
+            if d < D and fa.feature[t, n] >= 0:
+                feature[t, h] = fa.feature[t, n]
+                thresh[t, h] = fa.thresh[t, n]
+                stack += [(fa.left[t, n], 2 * h + 1, d + 1),
+                          (fa.right[t, n], 2 * h + 2, d + 1)]
+            else:
+                width = 2 ** (D - d)         # heap leaves under h
+                first = (h - (2 ** d - 1)) * width
+                leaf[t, first:first + width] = fa.value[t, n]
+    return feature, thresh, leaf
+
+
 def forest_predict_np(fa: ForestArrays, X: np.ndarray) -> np.ndarray:
     """Vectorized level-wise traversal: every (tree, row) pair walks one
     level per iteration; rows already at a leaf stay put. Bit-matches the
@@ -198,14 +229,57 @@ def forest_predict_np(fa: ForestArrays, X: np.ndarray) -> np.ndarray:
     return fa.value[rows, idx].mean(axis=0)
 
 
+# The select walk costs O(2^depth) per (tree, row); deeper forests keep
+# the gather traversal.
+DENSE_MAX_DEPTH = 8
+
+
+def _select(pos, *tables):
+    """table[:, pos] of each table, for pos (T, N) in [0, table.shape[1]),
+    by one chain of exact `jnp.where` selects that share their compares,
+    with no gather."""
+    outs = [jnp.broadcast_to(tb[:, :1], pos.shape) for tb in tables]
+    for j in range(1, tables[0].shape[1]):
+        hit = pos == j
+        outs = [jnp.where(hit, tb[:, j:j + 1], o)
+                for tb, o in zip(tables, outs)]
+    return outs
+
+
+@jax.jit
+def _forest_predict_device(feature, thresh, leaf, X):
+    """Gather-free walk over the heap layout (`forest_to_heap`): each
+    (tree, row) pair holds its node's position within the current level,
+    (T, N). The node's feature and threshold are picked by selects over
+    the level's 2^d nodes, the row's feature value by selects over the F
+    columns (clipped to the last, as the host walk clips), and a row steps
+    to 2p + (not x <= thresh), in float32. The leaf value is selected the
+    same way and averaged over trees. Every select is exact, so the result
+    is bit-identical to the gather traversal."""
+    T, depth = leaf.shape[0], leaf.shape[1].bit_length() - 1
+    N, F = X.shape
+    Xt = X.T
+    pos = jnp.zeros((T, N), jnp.int32)
+    for d in range(depth):
+        level = slice(2 ** d - 1, 2 ** (d + 1) - 1)
+        f, t = _select(pos, feature[:, level], thresh[:, level])
+        xv = jnp.broadcast_to(Xt[F - 1], (T, N))
+        for c in range(F - 1):
+            xv = jnp.where(f == c, Xt[c], xv)
+        pos = 2 * pos + jnp.logical_not(xv <= t).astype(jnp.int32)
+    leaf_value, = _select(pos, leaf)
+    return leaf_value.mean(axis=0)
+
+
 @functools.partial(jax.jit, static_argnames=("depth",))
-def _forest_predict_device(feature, thresh, left, right, value, offsets,
+def _forest_predict_gather(feature, thresh, left, right, value, offsets,
                            X, *, depth: int):
-    """Level-wise traversal over the flattened forest. All node fields are
-    1-D (total_nodes,) arrays and `offsets` (T, 1) holds each tree's root
-    index: 1-D `jnp.take` gathers lower much faster on CPU than the 2-D
-    take_along_axis equivalent. left/right store tree-local child indices,
-    hence the `offsets +` rebase each level."""
+    """Level-wise traversal over the flattened forest, for forests deeper
+    than `DENSE_MAX_DEPTH`. All node fields are 1-D (total_nodes,) arrays
+    and `offsets` (T, 1) holds each tree's root index: 1-D `jnp.take`
+    gathers lower much faster on CPU than the 2-D take_along_axis
+    equivalent. left/right store tree-local child indices, hence the
+    `offsets +` rebase each level."""
     T = offsets.shape[0]
     N, F = X.shape
     Xf = X.reshape(-1)
@@ -223,6 +297,12 @@ def _forest_predict_device(feature, thresh, left, right, value, offsets,
     idx = jax.lax.fori_loop(0, depth, body,
                             jnp.broadcast_to(offsets, (T, N)))
     return jnp.take(value, idx).mean(axis=0)
+
+
+def forest_dense(regressor) -> int:
+    """1 when `regressor.predict_device` runs the gather-free forest walk,
+    else 0: the `forest_dense` count of the search's spans."""
+    return int(getattr(regressor, "dense", False))
 
 
 class RandomForestRegressor:
@@ -319,20 +399,35 @@ class RandomForestRegressor:
         X = np.asarray(X, np.float32)
         return forest_predict_np(self.arrays(), X)
 
+    @property
+    def dense(self) -> bool:
+        """Whether `predict_device` runs the gather-free heap walk (depth
+        at most `DENSE_MAX_DEPTH`) rather than the gather traversal."""
+        return self.max_depth <= DENSE_MAX_DEPTH
+
     def predict_device(self, X):
         """jit-compatible prediction on a jnp feature batch; stays on
         device (the schedule search feeds simulator histograms straight in
-        with no host round-trip)."""
-        fa = self.arrays()
+        with no host round-trip). Forests of depth up to `DENSE_MAX_DEPTH`
+        are re-laid once per fit as complete heaps and walked with selects
+        (`_forest_predict_device`); deeper ones keep the gather traversal.
+        The two give the same bits; they pick the same leaves as
+        `predict`, whose numpy mean over trees may round differently."""
         if self._device_arrays is None:
-            T, M = fa.feature.shape
-            offsets = (np.arange(T, dtype=np.int32) * M)[:, None]
-            self._device_arrays = tuple(
-                jnp.asarray(a.reshape(-1))
-                for a in (fa.feature, fa.thresh, fa.left, fa.right,
-                          fa.value)) + (jnp.asarray(offsets),)
-        return _forest_predict_device(*self._device_arrays,
-                                      jnp.asarray(X), depth=fa.depth)
+            fa = self.arrays()
+            if self.dense:
+                self._device_arrays = (_forest_predict_device, tuple(
+                    jnp.asarray(a) for a in forest_to_heap(fa)))
+            else:
+                T, M = fa.feature.shape
+                offsets = (np.arange(T, dtype=np.int32) * M)[:, None]
+                self._device_arrays = (functools.partial(
+                    _forest_predict_gather, depth=fa.depth), tuple(
+                    jnp.asarray(a.reshape(-1))
+                    for a in (fa.feature, fa.thresh, fa.left, fa.right,
+                              fa.value)) + (jnp.asarray(offsets),))
+        fn, arrays = self._device_arrays
+        return fn(*arrays, jnp.asarray(X))
 
 
 # ---------------------------------------------------------------------------

@@ -66,7 +66,8 @@ from repro.core.search import (event_positions, infer_n_range,
                                score_candidates, select_candidate,
                                step_candidates)
 from repro.core.utility import (RandomForestRegressor, featurize,
-                                featurize_jnp, transfer_ready)
+                                featurize_jnp, forest_dense,
+                                transfer_ready)
 
 __all__ = ["ReplanService", "calibrate_forest", "rollout_histograms"]
 
@@ -439,7 +440,9 @@ class ReplanService:
             if rows1.size:
                 m = rows1.size
                 with tracing.span("replan.delta.score", window=window,
-                                  scheduled=m, bucket=_bucket(m)):
+                                  scheduled=m, bucket=_bucket(m),
+                                  forest_dense=forest_dense(
+                                      self.regressor)):
                     sel = np.concatenate(
                         [rows1, np.full(_bucket(m) - m, rows1[0], np.int64)])
                     marks, _, _ = step_candidates(

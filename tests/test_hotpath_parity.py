@@ -16,6 +16,7 @@ Strict-parity contract of the vectorization PRs:
     to the oracle.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,8 +26,9 @@ import pytest
 from repro.core import connectivity as CN
 from repro.core import staleness as SS
 from repro.core.search import fedspace_search, infer_n_range
-from repro.core.utility import (RandomForestRegressor, featurize,
-                                featurize_jnp)
+from repro.core.utility import (DENSE_MAX_DEPTH, RandomForestRegressor,
+                                _forest_predict_gather, _Node, featurize,
+                                featurize_jnp, forest_dense)
 from repro.data.fmow import FmowSpec, SyntheticFmow
 from repro.data.partition import iid_partition
 from repro.data.pipeline import make_clients
@@ -92,6 +94,113 @@ def test_device_predict_matches_node_walk():
     ref = rf.predict_reference(X)
     dev = np.asarray(rf.predict_device(jnp.asarray(X)))
     np.testing.assert_allclose(dev, ref, rtol=1e-5, atol=1e-6)
+
+
+def _gather_walk(rf, X):
+    """The gather traversal over the forest's flattened arrays: the form
+    `predict_device` served before the heap walk, and still serves past
+    `DENSE_MAX_DEPTH`."""
+    fa = rf.arrays()
+    T, M = fa.feature.shape
+    flat = [jnp.asarray(a.reshape(-1))
+            for a in (fa.feature, fa.thresh, fa.left, fa.right, fa.value)]
+    offsets = jnp.asarray((np.arange(T, dtype=np.int32) * M)[:, None])
+    return np.asarray(_forest_predict_gather(*flat, offsets, jnp.asarray(X),
+                                             depth=fa.depth))
+
+
+def _heap_forest(seed, *, depth=6, n_trees=30, F=13):
+    """A complete heap-shaped forest (inner nodes 0..2^D - 2, children
+    2n + 1 and 2n + 2), the layout of a forest drawn rather than fitted."""
+    rng = np.random.default_rng(seed)
+    n_inner = 2 ** depth - 1
+    trees = [[_Node(feature=int(rng.integers(F)) if n < n_inner else -1,
+                    thresh=float(rng.random()),
+                    left=2 * n + 1 if n < n_inner else -1,
+                    right=2 * n + 2 if n < n_inner else -1,
+                    value=float(rng.normal()))
+              for n in range(2 * n_inner + 1)] for _ in range(n_trees)]
+    rf = RandomForestRegressor(n_trees=n_trees, max_depth=depth)
+    rf.trees = trees
+    return rf, rng
+
+
+@functools.lru_cache(maxsize=None)
+def _forest_case(kind, depth):
+    if kind == "heap":
+        return _heap_forest(depth, depth=depth)[0]
+    # enough samples that the deepest levels are reached, unevenly
+    return _fit_forest(depth, n_trees=10, max_depth=depth,
+                       n=250 * depth)[0]
+
+
+def _rows_on_thresholds(rf, rng, n):
+    """(n, 13) rows in [0, 1), the first half with one feature set exactly
+    to a split threshold of the forest."""
+    fa = rf.arrays()
+    X = rng.random((n, 13)).astype(np.float32)
+    trees, nodes = np.nonzero(fa.feature >= 0)
+    pick = rng.integers(len(trees), size=n // 2)
+    X[np.arange(n // 2), fa.feature[trees[pick], nodes[pick]]] = \
+        fa.thresh[trees[pick], nodes[pick]]
+    return X
+
+
+FOREST_CASES = [("fit", 2), ("fit", 5), ("fit", 6), ("fit", 8), ("heap", 6)]
+
+
+@pytest.mark.parametrize("kind,depth", FOREST_CASES)
+def test_heap_walk_bitmatches_gather_walk(kind, depth):
+    """The gather-free heap walk returns the gather traversal's bits, on
+    unbalanced fitted forests, a complete heap, and rows that sit exactly
+    on a split threshold (`x <= thresh` goes left in both)."""
+    rf, rng = _forest_case(kind, depth), np.random.default_rng(depth)
+    assert rf.dense and forest_dense(rf) == 1
+    X = _rows_on_thresholds(rf, rng, 5_000)
+    dev = np.asarray(rf.predict_device(jnp.asarray(X)))
+    assert np.array_equal(dev, _gather_walk(rf, X))
+    np.testing.assert_allclose(dev[:300], rf.predict_reference(X[:300]),
+                               rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind,depth", FOREST_CASES)
+def test_heap_walk_row_is_batch_invariant(kind, depth):
+    """A row's prediction does not depend on its batch: alone, in a
+    1,024-row delta bucket and in a 5,000 x 12 full-rescan batch. The
+    replan service's delta == full contract rests on this."""
+    rf, rng = _forest_case(kind, depth), np.random.default_rng(depth)
+    probe = _rows_on_thresholds(rf, rng, 16)
+    alone = np.concatenate([np.asarray(rf.predict_device(jnp.asarray(x)))
+                            for x in probe[:, None]])
+    for n in (1_024, 5_000 * 12):
+        X = rng.random((n, 13)).astype(np.float32)
+        at = rng.choice(n, len(probe), replace=False)
+        X[at] = probe
+        batch = np.asarray(rf.predict_device(jnp.asarray(X)))
+        assert np.array_equal(batch[at], alone), n
+
+
+def test_deep_forest_keeps_gather_walk():
+    """Past `DENSE_MAX_DEPTH` the select walk's O(2^depth) cost is not
+    paid: a depth-9 forest serves the gather traversal, with the same
+    values alone as in a batch."""
+    rf, rng = _fit_forest(9, n_trees=3, max_depth=DENSE_MAX_DEPTH + 1,
+                          n=2_500)
+    assert not rf.dense and forest_dense(rf) == 0
+    X = _rows_on_thresholds(rf, rng, 1_024)
+    dev = np.asarray(rf.predict_device(jnp.asarray(X)))
+    assert rf._device_arrays[0].func is _forest_predict_gather
+    assert np.array_equal(dev, _gather_walk(rf, X))
+    np.testing.assert_allclose(dev[:300], rf.predict_reference(X[:300]),
+                               rtol=1e-5, atol=1e-6)
+    alone = np.concatenate([np.asarray(rf.predict_device(jnp.asarray(x)))
+                            for x in X[:8, None]])
+    assert np.array_equal(alone, dev[:8])
+
+
+def test_forest_dense_counts_only_the_heap_walk():
+    assert forest_dense(_NodeWalkHost(None)) == 0
+    assert forest_dense(_heap_forest(0, depth=DENSE_MAX_DEPTH)[0]) == 1
 
 
 def test_featurize_jnp_matches_host():

@@ -125,3 +125,29 @@ def test_transformer_grad_compiles_through_kernels(topo, one_chip,
                              sharding=one_chip)
     y = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
     _compile(jax.grad(ad.loss), p, (X, y))
+
+
+@pytest.mark.parametrize("rows", [60_000, 1_024])
+def test_forest_walk_compiles_without_gathers(topo, one_chip, rows):
+    """The eq.-13 forest at the replan pool's size (5,000 candidates by
+    up to 12 events) and a delta bucket: 30 trees of depth 6 over 13
+    features. The heap walk must lower to selects alone; the gather
+    traversal deeper forests keep shows the probe finds a gather."""
+    import functools
+    from repro.core.utility import (_forest_predict_device,
+                                    _forest_predict_gather)
+    T, D, F, M = 30, 6, 13, 2 ** 7 - 1
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    X = spec((rows, F), jnp.float32)
+    dense = _forest_predict_device.lower(
+        spec((T, 2 ** D - 1), jnp.int32), spec((T, 2 ** D - 1), jnp.float32),
+        spec((T, 2 ** D), jnp.float32), X).compile()
+    assert " gather(" not in dense.as_text()
+    flat = [spec((T * M,), dt) for dt in (jnp.int32, jnp.float32, jnp.int32,
+                                         jnp.int32, jnp.float32)]
+    walk = jax.jit(functools.partial(_forest_predict_gather, depth=D)).lower(
+        *flat, spec((T, 1), jnp.int32), X).compile()
+    assert " gather(" in walk.as_text()

@@ -144,6 +144,7 @@ def test_spans_nest_in_their_request(traced):
             assert 1 <= reduce_[4]["width"] <= I0
             for score in (s for s in inner if s[0] == "replan.delta.score"):
                 assert 1 <= score[4]["scheduled"] <= score[4]["bucket"]
+                assert score[4]["forest_dense"] == 1
         else:
             assert {"replan.full", "replan.full.draw", "search.scan",
                     "search.chunk", "search.fetch"} <= names
@@ -151,7 +152,7 @@ def test_spans_nest_in_their_request(traced):
             full, = [s for s in inner if s[0] == "replan.full"]
             assert full[4]["reason"] == reason
             scan, = [s for s in inner if s[0] == "search.scan"]
-            assert scan[4] == {"rows": 64, "chunks": 1}
+            assert scan[4] == {"rows": 64, "chunks": 1, "forest_dense": 1}
     assert any(s[0] == "replan.delta.score" for s in spans)
     assert [m for m, _, _ in seen].count("full") == 2
     assert seen[0][1] == "cold" and seen[5][1] == "status"
