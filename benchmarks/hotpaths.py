@@ -344,7 +344,7 @@ def bench_search_scaling(smoke: bool) -> dict:
 # 2. aggregation round
 
 
-def _seed_aggregate(eng, i: int):
+def _seed_aggregate(eng, i: int, client_update):
     """The seed engine's `on_aggregate` hot loop (one dispatch + checkpoint
     fetch per satellite, sequential compression, stack-tensordot-add),
     without the bookkeeping; returns the new global params."""
@@ -355,8 +355,8 @@ def _seed_aggregate(eng, i: int):
     updates = []
     for k in ks:
         base = eng.store.get(int(buffered[k]))
-        u = eng._client_update(base, int(k), round_rng=i,
-                               batch_size=cfg.batch_size)
+        u = client_update(base, int(k), round_rng=i,
+                          batch_size=cfg.batch_size)
         if cfg.uplink_topk > 0.0:
             u, _ = roundtrip(u, cfg.uplink_topk)
         updates.append(u)
@@ -378,9 +378,10 @@ def _batched_aggregate(eng, i: int):
     cfg = eng.config
     buffered = eng.buffered_base
     ks = np.flatnonzero(buffered >= 0)
-    stal = eng.ig - buffered[ks]
-    stack = eng._train_buffered(ks, buffered, round_rng=i)
-    w = aggregation_weights(jnp.asarray(stal), cfg.alpha) * cfg.server_lr
+    stack = eng._train_event(ks, buffered[ks], round_rng=i)
+    stal = np.full(jax.tree.leaves(stack)[0].shape[0], -1, np.int32)
+    stal[:len(ks)] = eng.ig - buffered[ks]
+    w = aggregation_weights(stal, cfg.alpha, cfg.server_lr)
     return aggregate_params_tree(eng.params, stack, w)
 
 
@@ -425,7 +426,10 @@ def bench_aggregation(smoke: bool) -> dict:
         return min(ts), out
 
     t_opt, p_opt = timed(_batched_aggregate)
-    t_ref, p_ref = timed(_seed_aggregate)
+    from repro.fl.client import make_client_update
+    cu = make_client_update(adapter, local_steps=eng.config.local_steps,
+                            lr=eng.config.client_lr)
+    t_ref, p_ref = timed(lambda e, i: _seed_aggregate(e, i, cu))
     bit_equal = all(
         np.array_equal(np.asarray(a), np.asarray(b))
         for a, b in zip(jax.tree.leaves(p_ref), jax.tree.leaves(p_opt)))

@@ -290,7 +290,7 @@ def fedspace_experiment(*, preset="flock191", days=1.0, num_train=36_000,
 
 def client_update_hlo(fed: Federation) -> str:
     """Lowered text of the engine's jitted batched client update (same
-    program `SimulationEngine.prepare` builds) for a 2-satellite group."""
+    program `SimulationEngine.prepare` builds) for a 2-satellite event."""
     cfg = fed.experiment.train
     update_many = make_batched_client_update(
         fed.adapter, local_steps=cfg.local_steps, lr=cfg.client_lr)
@@ -298,7 +298,10 @@ def client_update_hlo(fed: Federation) -> str:
         [0, 1], 0, cfg.batch_size, cfg.local_steps)
     check(rows, "no client batch to lower the update with")
     params = fed.adapter.init(jax.random.PRNGKey(0))
-    return update_many.lower(params, batches).as_text()
+    m = jax.tree.leaves(batches)[0].shape[0]
+    bases = jax.tree.map(lambda p: jnp.broadcast_to(p, (m,) + p.shape),
+                         params)
+    return update_many.lower(bases, batches).as_text()
 
 
 def phase_federation(exp: FLExperiment) -> dict:

@@ -168,6 +168,8 @@ class DeviceCheckpointStore:
             self._ring = jax.tree.map(
                 lambda l: jnp.zeros((self.keep,) + l.shape, l.dtype),
                 params)
+            # compile the spill read with the ring, not at the first spill
+            _ring_read(self._ring, jnp.int32(0))
         slot = version % self.keep
         evicted = self._slot_ver[slot]
         if evicted is not None and evicted != version \
@@ -202,14 +204,14 @@ class DeviceCheckpointStore:
 
     def get_many(self, versions):
         """Stacked device gather of several in-ring versions (leading axis
-        = len(versions)); falls back to per-version `get` + stack when any
-        requested version has spilled off the ring."""
+        = len(versions), one program per length); when any requested
+        version has spilled off the ring, the per-version `get`s are
+        stacked on the host and uploaded once, which compiles nothing."""
         slots = [self._ver_slot.get(v) for v in versions]
         if all(s is not None for s in slots):
-            return _ring_gather(self._ring,
-                                jnp.asarray(slots, jnp.int32))
-        trees = [self.get(v) for v in versions]
-        return jax.tree.map(lambda *ls: jnp.stack(ls), *trees)
+            return _ring_gather(self._ring, np.asarray(slots, np.int32))
+        return jax.tree.map(lambda *ls: jnp.asarray(np.stack(ls)),
+                            *[self.get(v) for v in versions])
 
     def prune(self, min_referenced: int) -> None:
         """Same retention rule as `CheckpointStore.prune`, applied to ring

@@ -9,6 +9,8 @@ kernel on TPU, the bit-identical pure-jnp reduction elsewhere.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -16,10 +18,15 @@ from repro.core.staleness import staleness_compensation
 from repro.kernels.agg.ops import aggregate_params_tree
 
 
-def aggregation_weights(staleness, alpha: float = 0.5):
-    """Normalized c(s_k)/C weights. staleness: (M,) int array."""
-    c = staleness_compensation(jnp.asarray(staleness), alpha)
-    return c / jnp.maximum(jnp.sum(c), 1e-12)
+@functools.partial(jax.jit, static_argnames=("alpha",))
+def aggregation_weights(staleness, alpha: float = 0.5, server_lr=1.0):
+    """Normalized c(s_k)/C weights times `server_lr`, one program per
+    length. staleness: (M,) int array; rows of negative staleness are
+    padding and weigh 0."""
+    s = jnp.asarray(staleness)
+    c = jnp.where(s >= 0, staleness_compensation(jnp.maximum(s, 0), alpha),
+                  0.0)
+    return c / jnp.maximum(jnp.sum(c), 1e-12) * server_lr
 
 
 def apply_aggregation(global_params, update_stack, staleness, *,
@@ -31,6 +38,6 @@ def apply_aggregation(global_params, update_stack, staleness, *,
     Returns updated params. `interpret` forwards to the kernel dispatch
     (None = kernel on TPU, jnp reduction elsewhere).
     """
-    w = aggregation_weights(staleness, alpha) * server_lr
+    w = aggregation_weights(staleness, alpha, server_lr)
     return aggregate_params_tree(global_params, update_stack, w,
                                  interpret=interpret)

@@ -510,6 +510,12 @@ def _pad_rows(tree, bucket: int):
                                  + b.shape[1:])], axis=0), tree)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _tile(tree, n: int):
+    """`tree` stacked n times on a new leading axis (one base per row)."""
+    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), tree)
+
+
 @functools.partial(jax.jit, static_argnames=("n_seg",))
 def _segment_accumulate(totals, upd, seg, w, *, n_seg):
     """totals[n] += sum over rows with seg == n of w_row * upd_row, per
@@ -549,7 +555,7 @@ def generate_utility_samples(
 
     When the batched machinery is supplied — ``batch_fn(ci, rng_int)``
     returning the client's training batch (or None for an empty shard),
-    ``batched_update_fn(base, stacked_batches)`` (e.g.
+    ``batched_update_fn(stacked_bases, stacked_batches)`` (e.g.
     `repro.fl.client.make_batched_client_update`), and
     ``batched_loss_fn(stacked_params) -> (M,) losses`` — generation is
     vectorized on the engine's machinery: sampled client updates are
@@ -620,7 +626,7 @@ def generate_utility_samples(
             # in power-of-two buckets — padded rows carry zero weight
             blist = [b for _, b in mem] + [mem[0][1]] * (bucket - m)
             batches = jax.tree.map(lambda *bs: jnp.stack(bs), *blist)
-            upd = batched_update_fn(base, batches)
+            upd = batched_update_fn(_tile(base, bucket), batches)
             rows = [idx for idx, _ in mem]
             seg = np.zeros(bucket, np.int32)
             w = np.zeros(bucket, np.float32)

@@ -4,6 +4,13 @@ from __future__ import annotations
 import numpy as np
 
 
+def row_bucket(n: int) -> int:
+    """The rows a batch for n clients is padded to: the next power of two
+    at or above n, so the programs that train it meet one shape per
+    bucket."""
+    return 1 << (n - 1).bit_length()
+
+
 class ClientDataset:
     """A satellite's local shard: deterministic minibatch stream."""
 

@@ -13,7 +13,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, StageSpec
 from repro.data.fmow import NUM_CLASSES, SyntheticFmow
-from repro.data.pipeline import ClientDataset
+from repro.data.pipeline import ClientDataset, row_bucket
 from repro.fl.registry import register_adapter
 from repro.kernels.flash_attention.ops import flash_attention_bshd
 from repro.kernels.rmsnorm.ops import rmsnorm as rmsnorm_op
@@ -77,9 +77,10 @@ class MlpFmowAdapter:
     def _client_batch_indices(self, client_ids, round_rng: int,
                               batch_size: int, num_batches: int):
         """Index batches for a client set, restricted to the modal batch
-        width so they stack. Returns (idx (M, num_batches, b), rows), rows
-        being the positions of `client_ids` included; clients with empty
-        shards or off-modal widths are left to the per-client fallback."""
+        width so they stack. Returns (idx (B, num_batches, b), rows), rows
+        being the positions of `client_ids` included and B =
+        `row_bucket(len(client_ids))`; idx rows past len(rows) repeat row
+        0. Clients with empty shards or off-modal widths are left out."""
         idxs = [self.clients[i].batches(round_rng, batch_size, num_batches)
                 for i in client_ids]
         widths = [ix.shape[1] for ix in idxs]
@@ -91,13 +92,16 @@ class MlpFmowAdapter:
             return None, []
         modal = max(counts, key=lambda w: (counts[w], w))
         rows = [r for r, w in enumerate(widths) if w == modal]
-        return np.stack([idxs[r] for r in rows]), rows
+        pad = row_bucket(len(client_ids)) - len(rows)
+        return np.stack([idxs[r] for r in rows + rows[:1] * pad]), rows
 
     def client_batch_many(self, client_ids, round_rng: int, batch_size: int,
                           num_batches: int):
         """Batched `client_batch`: one host gather + one device transfer
         for the whole client set (bit-identical batches to the per-client
-        calls). Returns (stacked batch with leading dim M, rows)."""
+        calls). Returns (stacked batch, rows): stacked row j is client
+        `client_ids[rows[j]]`'s batch, and rows past len(rows), up to
+        `row_bucket(len(client_ids))`, repeat row 0."""
         idx, rows = self._client_batch_indices(client_ids, round_rng,
                                                batch_size, num_batches)
         if not rows:

@@ -53,7 +53,8 @@ from repro.core import mesh as MM
 from repro.core import staleness as SS
 from repro.core.aggregation import aggregation_weights
 from repro.core.scheduler import Scheduler
-from repro.fl.client import make_batched_client_update, make_client_update
+from repro.data.pipeline import row_bucket
+from repro.fl.client import make_batched_client_update
 from repro.kernels.agg.ops import aggregate_params_tree
 
 T0_MINUTES = 15.0
@@ -61,6 +62,36 @@ T0_MINUTES = 15.0
 # Upper bound on windows per jitted scan: chunks are bucketed to powers of
 # two up to this, so the scan compiles O(log) shapes per scheduler kind.
 _MAX_CHUNK = 128
+
+
+# ---------------------------------------------------------------------------
+# an aggregation event's rows
+
+
+@jax.jit
+def _take_rows(params, stacks, src):
+    """Rows `src` of the update stacks laid end to end; the index just past
+    their end is an exact-zero row."""
+    return jax.tree.map(
+        lambda p, *us: jnp.concatenate(
+            us + (jnp.zeros((1,) + p.shape, p.dtype),))[src],
+        params, *stacks)
+
+
+def _client_batch_many(adapter, client_ids, *args):
+    """`client_batch_many` for an adapter with only `client_batch`: the
+    clients whose batch has the first batch's shapes, stacked on the host
+    and padded to `row_bucket(len(client_ids))` rows."""
+    got = [adapter.client_batch(int(k), *args) for k in client_ids]
+    shapes = [None if b is None else [np.shape(x) for x in jax.tree.leaves(b)]
+              for b in got]
+    first = next((g for g in shapes if g is not None), None)
+    rows = [r for r, g in enumerate(shapes) if g is not None and g == first]
+    if not rows:
+        return None, []
+    pad = row_bucket(len(client_ids)) - len(rows)
+    return jax.tree.map(lambda *xs: jnp.asarray(np.stack(xs)), *[
+        got[r] for r in rows + rows[:1] * pad]), rows
 
 
 # ---------------------------------------------------------------------------
@@ -608,9 +639,6 @@ class SimulationEngine:
                        else self._init_params)
         mask = self.adapter.trainable_mask(self.params) \
             if hasattr(self.adapter, "trainable_mask") else None
-        self._client_update = make_client_update(
-            self.adapter, local_steps=cfg.local_steps, lr=cfg.client_lr,
-            trainable_mask=mask)
         self._batched_update = make_batched_client_update(
             self.adapter, local_steps=cfg.local_steps, lr=cfg.client_lr,
             trainable_mask=mask, uplink_topk=cfg.uplink_topk,
@@ -923,24 +951,23 @@ class SimulationEngine:
     def on_aggregate(self, i: int) -> None:
         """Apply the staleness-compensated buffered update (eq. 4).
 
-        Client training is batched: buffered satellites are grouped by base
-        model version (and batch shape), each group trains under one
-        vmapped jitted call — with the optional uplink compression fused in
-        (see `make_batched_client_update`) — instead of one dispatch plus
-        checkpoint fetch per satellite. Base checkpoints come out of the
-        device ring (`DeviceCheckpointStore`), so no host→device transfer
-        per base version; the weighted reduction routes through the
-        aggregation kernel (`aggregate_params_tree`: Pallas on TPU,
-        bit-identical jnp elsewhere). The buffer contents are materialized
-        to host once here — the grouping and data gather are host work.
+        The n buffered satellites train as one batch of B rows, B the next
+        power of two at or above n, each row on its own base model
+        (`_train_event`). The staleness vector is padded to B with rows
+        that weigh 0, and the reduction routes through the aggregation
+        kernel (`aggregate_params_tree`: Pallas on TPU, bit-identical jnp
+        elsewhere). Every program of an event is shaped by B alone, so an
+        event compiles nothing once its bucket has been met. The buffer
+        is read to host once, for the data gather and the bookkeeping.
         """
         cfg = self.config
         buffered = np.asarray(self.state.buffered)
         ks = np.flatnonzero(buffered >= 0)
-        stal = (self.ig - buffered[ks]).astype(np.int64)
-        stack = self._train_buffered(ks, buffered, round_rng=i)
-        w = aggregation_weights(jnp.asarray(stal), cfg.alpha) \
-            * cfg.server_lr
+        stal = (self.ig - buffered[ks]).astype(np.int32)
+        stack = self._train_event(ks, buffered[ks], round_rng=i)
+        padded = np.full(jax.tree.leaves(stack)[0].shape[0], -1, np.int32)
+        padded[:len(ks)] = stal
+        w = aggregation_weights(padded, cfg.alpha, cfg.server_lr)
         self.params = aggregate_params_tree(self.params, stack, w)
         self.state = _aggregate_state(self.state, jnp.int32(self.ig),
                                       s_max=cfg.s_max)
@@ -957,76 +984,38 @@ class SimulationEngine:
                    {"ig": self.ig, "n_aggregated": len(ks),
                     "staleness": stal.tolist()})
 
-    def _train_buffered(self, ks: np.ndarray, buffered: np.ndarray, *,
-                        round_rng: int):
-        """Compute the buffered satellites' updates, batched by base model
-        version. Returns the update stack (leading dim len(ks)) in `ks`
-        order, matching the staleness vector.
+    def _train_event(self, ks: np.ndarray, versions: np.ndarray, *,
+                     round_rng: int):
+        """The updates of satellites `ks`, trained on base `versions`, as
+        one stack of B = `row_bucket(len(ks))` rows: row r is ks[r]'s
+        update, rows past len(ks) are exact zeros.
 
-        Per base version: one checkpoint fetch (a device ring gather), one
-        batched data gather (`adapter.client_batch_many` when available — a
-        single host gather + device transfer), one vmapped jitted training
-        call. Satellites the batched gather can't serve (empty shards,
-        off-modal batch widths) fall back to per-satellite batches, grouped
-        by shape."""
+        One `client_batch_many` call serves the satellites at the modal
+        batch width; the rest keep a call at their own width (fixed per
+        shard), and an empty shard gets an exact-zero row. A call's batch
+        comes padded to its `row_bucket`, its rows' bases out of the device
+        ring (`store.get_many`), and it trains in one vmapped program
+        (`make_batched_client_update`, uplink compression fused in): one
+        program per (bucket, width)."""
         cfg = self.config
-        by_base = {}   # base version -> [(row in ks, client id)]
-        for row, k in enumerate(ks):
-            by_base.setdefault(int(buffered[k]), []).append((row, int(k)))
-        many = getattr(self.adapter, "client_batch_many", None)
-        order, chunks, zero_rows = [], [], []
-        for base_v, members in by_base.items():
-            base = self.store.get(base_v)       # fetched once per group
-            rest = range(len(members))
-            if many is not None:
-                stacked, used = many([k for _, k in members], round_rng,
-                                     cfg.batch_size, cfg.local_steps)
-                if used:
-                    chunks.append(self._run_batched(base, stacked,
-                                                    len(used)))
-                    order += [members[u][0] for u in used]
-                    rest = [j for j in rest if j not in set(used)]
-            by_shape = {}  # leftovers / no batched gather: group by shape
-            for j in rest:
-                row, k = members[j]
-                batch = self.adapter.client_batch(k, round_rng,
-                                                  cfg.batch_size,
-                                                  cfg.local_steps)
-                if batch is None:
-                    zero_rows.append(row)
-                    continue
-                sig = tuple(tuple(leaf.shape)
-                            for leaf in jax.tree.leaves(batch))
-                by_shape.setdefault(sig, []).append((row, batch))
-            for mem in by_shape.values():
-                batches = jax.tree.map(lambda *bs: jnp.stack(bs),
-                                       *[b for _, b in mem])
-                chunks.append(self._run_batched(base, batches, len(mem)))
-                order += [row for row, _ in mem]
-        if zero_rows:
-            chunks.append(jax.tree.map(
-                lambda p: jnp.zeros((len(zero_rows),) + p.shape, p.dtype),
-                self.params))
-            order += zero_rows
-        inv = np.argsort(np.asarray(order))     # back to ks order
-        return jax.tree.map(lambda *cs: jnp.concatenate(cs, axis=0)[inv],
-                            *chunks)
-
-    def _run_batched(self, base, batches, m: int):
-        """Run the vmapped client-update program on a group of m
-        satellites, padded to the next power of two (repeating row 0) so
-        the jitted program compiles O(log K) distinct batch sizes over a
-        run instead of one per observed group size. Rows are independent
-        under vmap, so the real rows are unaffected by padding."""
-        bucket = 1 << (m - 1).bit_length()
-        if bucket == m:
-            return self._batched_update(base, batches)
-        batches = jax.tree.map(
-            lambda b: jnp.concatenate(
-                [b, jnp.broadcast_to(b[:1], (bucket - m,) + b.shape[1:])],
-                axis=0), batches)
-        return jax.tree.map(lambda u: u[:m],
-                            self._batched_update(base, batches))
+        many = getattr(self.adapter, "client_batch_many", None) \
+            or functools.partial(_client_batch_many, self.adapter)
+        rest, stacks, off = np.arange(len(ks)), [], 0
+        src = np.full(row_bucket(len(ks)), -1, np.int32)
+        while rest.size:
+            batches, used = many(ks[rest], round_rng, cfg.batch_size,
+                                 cfg.local_steps)
+            if not used:                  # only empty shards are left
+                break
+            m = jax.tree.leaves(batches)[0].shape[0]
+            rows = rest[used + used[:1] * (m - len(used))]
+            bases = self.store.get_many(versions[rows].tolist())
+            stacks.append(self._batched_update(bases, batches))
+            src[rows[:len(used)]] = off + np.arange(len(used))
+            off += m
+            rest = np.delete(rest, used)
+        src[src < 0] = off                # the exact-zero row
+        return _take_rows(self.params, tuple(stacks), src)
 
     def on_downloads(self, i: int, conn: np.ndarray) -> None:
         """Connected satellites fetch the current global model and start a

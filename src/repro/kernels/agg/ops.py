@@ -1,4 +1,4 @@
-"""jit'd public API for the aggregation kernel: flat and pytree forms.
+"""jit'd public API for the aggregation kernel over a parameter pytree.
 
 Dispatch policy (`interpret=None`, the default): on TPU the compiled Pallas
 kernel runs; off TPU the pure-jnp oracle runs instead. The oracle is
@@ -11,41 +11,40 @@ logic off-TPU).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import on_tpu
 from repro.kernels.agg.kernel import weighted_aggregate
-from repro.kernels.agg.ref import weighted_aggregate_ref
-
-
-def aggregate_flat(params_flat, updates, weights, *, interpret=None):
-    if interpret is None:
-        if on_tpu():
-            interpret = False
-        else:
-            return weighted_aggregate_ref(params_flat, updates, weights)
-    return weighted_aggregate(params_flat, updates, weights,
-                              interpret=interpret)
-
-
-def weighted_aggregate_tree(update_stack, weights, *, interpret=None):
-    """update_stack: pytree with leading buffer dim M -> weighted sum tree
-    (flattens each leaf through the kernel)."""
-    def one(u):
-        m = u.shape[0]
-        flat = u.reshape(m, -1)
-        zero = jnp.zeros((flat.shape[1],), jnp.float32)
-        out = aggregate_flat(zero, flat, weights, interpret=interpret)
-        return out.reshape(u.shape[1:])
-    return jax.tree.map(one, update_stack)
+from repro.kernels.agg.ref import weighted_sum_ref
 
 
 def aggregate_params_tree(params, update_stack, weights, *, interpret=None):
-    """params + sum_m w_m * updates[m] per leaf, through the kernel."""
+    """params + sum_m w_m * updates[m] per leaf, through the kernel: one
+    jitted program for each (M, tree structure)."""
+    if interpret is None and on_tpu():
+        interpret = False
+    return _aggregate_tree(params, update_stack, weights,
+                           interpret=interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _aggregate_tree(params, update_stack, weights, *, interpret):
+    """(new params, the oracle's weighted sums): `interpret` None runs the
+    oracle, a bool the Pallas kernel (no sums). The sums leave the
+    program so that XLA's CPU backend cannot fold `p + dot` into one
+    fusion, which rounds differently from the eager reduction."""
     def one(p, u):
-        m = u.shape[0]
-        out = aggregate_flat(p.reshape(-1).astype(jnp.float32),
-                             u.reshape(m, -1), weights, interpret=interpret)
-        return out.reshape(p.shape).astype(p.dtype)
-    return jax.tree.map(one, params, update_stack)
+        flat = p.reshape(-1).astype(jnp.float32)
+        u = u.reshape(u.shape[0], -1)
+        if interpret is None:
+            acc = weighted_sum_ref(u, weights)
+            return (flat + acc).reshape(p.shape).astype(p.dtype), acc
+        out = weighted_aggregate(flat, u, weights, interpret=interpret)
+        return out.reshape(p.shape).astype(p.dtype), None
+
+    leaves, tree = jax.tree.flatten(params)
+    outs, sums = zip(*map(one, leaves, jax.tree.leaves(update_stack)))
+    return jax.tree.unflatten(tree, outs), sums

@@ -17,7 +17,7 @@ import pytest
 import repro.fl.adapters  # noqa: F401 — registers the built-in adapters
 from repro.data.fmow import FmowSpec, SyntheticFmow
 from repro.data.partition import iid_partition
-from repro.data.pipeline import make_clients
+from repro.data.pipeline import make_clients, row_bucket
 from repro.fl.client import make_batched_client_update
 from repro.fl.registry import ADAPTERS
 
@@ -68,17 +68,19 @@ def _tree_equal(a, b):
 def test_client_batch_many_bit_identical_to_per_client(adapter):
     """The stacked fast-path batch must reproduce the sequential
     `client_batch` calls bit for bit for every included row — the engine's
-    seed-trajectory guarantee rests on this."""
+    seed-trajectory guarantee rests on this. The stack is padded to the
+    next power of two at or above the clients asked for, with copies of
+    its first row."""
     for round_rng in (3, 17):
         stacked, rows = adapter.client_batch_many(list(range(K)), round_rng,
                                                   16, 2)
         assert rows == sorted(rows)
         assert set(rows) <= set(range(K))
         assert len(rows) > 0
-        M = len(rows)
+        M = row_bucket(K)
         for leaf in jax.tree.leaves(stacked):
             assert leaf.shape[0] == M
-        for pos, cid in enumerate(rows):
+        for pos, cid in enumerate(rows + rows[:1] * (M - len(rows))):
             single = adapter.client_batch(cid, round_rng, 16, 2)
             assert single is not None
             got = jax.tree.map(lambda s: s[pos], stacked)
@@ -121,10 +123,10 @@ def test_accuracy_and_val_loss_are_finite(adapter):
 
 
 def test_batched_update_matches_param_pytree(adapter):
-    """Client updates are deltas over the parameter pytree: identical
-    treedef, and per-leaf shapes/dtypes with the stacked leading axis M —
-    what the staleness aggregation and the compression roundtrip both
-    assume."""
+    """Client updates are deltas over the parameter pytree, each row over
+    its own base: identical treedef, and per-leaf shapes/dtypes with the
+    stacked leading axis M — what the staleness aggregation and the
+    compression roundtrip both assume."""
     params = adapter.init(jax.random.PRNGKey(1))
     mask = (adapter.trainable_mask(params)
             if hasattr(adapter, "trainable_mask") else None)
@@ -133,9 +135,11 @@ def test_batched_update_matches_param_pytree(adapter):
     update_many = make_batched_client_update(
         adapter, local_steps=2, lr=0.1, trainable_mask=mask)
     stacked, rows = adapter.client_batch_many(list(range(K)), 5, 16, 2)
-    u = update_many(params, stacked)
+    M = jax.tree.leaves(stacked)[0].shape[0]
+    bases = jax.tree.map(lambda p: jnp.broadcast_to(p, (M,) + p.shape),
+                         params)
+    u = update_many(bases, stacked)
     assert jax.tree.structure(u) == jax.tree.structure(params)
-    M = len(rows)
     for du, p in zip(jax.tree.leaves(u), jax.tree.leaves(params)):
         assert du.shape == (M,) + p.shape
         assert du.dtype == p.dtype

@@ -139,6 +139,32 @@ def test_device_checkpoint_store_contract():
         st.get(4)
 
 
+def test_device_checkpoint_store_spilled_bases_compile_nothing():
+    """Once the ring exists, spilling versions off it and gathering
+    per-row bases of which some have spilled (stacked on the host and
+    uploaded once) compile no program, however many leaves the model
+    has."""
+    st = DeviceCheckpointStore(ring=2)
+    trees = [{"a": jnp.full(3, v), "b": jnp.full((2, 2), v),
+              "c": jnp.full(5, v)} for v in range(4)]
+    st.put(0, trees[0])
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for v in range(1, 4):                 # 2, 3 in ring, 0, 1 spilled
+            st.put(v, trees[v])
+        stacked = st.get_many([0, 1, 2, 3, 3])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert np.asarray(stacked["b"])[:, 1, 1].tolist() == [0, 1, 2, 3, 3]
+
+
 def test_device_checkpoint_store_overwrites_in_place():
     """Re-putting a version replaces the slot content (no stale host
     copy resurfacing)."""

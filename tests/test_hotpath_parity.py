@@ -455,6 +455,47 @@ def test_batched_aggregate_handles_empty_shards():
     assert new.accuracy == ref.accuracy
 
 
+@pytest.mark.parametrize("scheme,kw", [("fedbuff", {"M": 6}),
+                                       ("async", {})])
+def test_batched_aggregate_mixed_event_matches_seed(scheme, kw):
+    """Events whose buffer mixes base versions, a satellite whose shard is
+    smaller than the batch (an off-modal batch width) and one with an
+    empty shard train as one batch of rows and match the seed's
+    per-satellite engine: integer counters exactly, floats within the
+    tolerances of `test_batched_aggregate_bit_identical_trajectory`."""
+    K = 12
+    C = np.random.default_rng(1).random((48, K)) < 0.35
+    data = SyntheticFmow(FmowSpec(num_train=400, num_val=200))
+    parts = iid_partition(360, K - 3, 0) + [
+        np.arange(360, 370), np.arange(370, 375), np.array([], np.int64)]
+    adapter = MlpFmowAdapter(data, make_clients(parts))
+    cfg = EngineConfig(eval_every=16, max_windows=48)
+    ref_eng = _SeedHostEngine(C, adapter, make_scheduler(scheme, **kw), cfg)
+    ref = ref_eng.run()
+    new_eng = SimulationEngine(C, adapter, make_scheduler(scheme, **kw),
+                               cfg)
+    aggregate, mixed = new_eng.on_aggregate, []
+
+    def spy(i):
+        b = np.asarray(new_eng.state.buffered)
+        mixed.append(len(set(b[b >= 0])) > 1 and b[K - 1] >= 0
+                     and max(b[K - 3], b[K - 2]) >= 0)
+        aggregate(i)
+
+    new_eng.on_aggregate = spy
+    new = new_eng.run()
+    assert any(mixed)
+    floats = ("final_acc", "best_acc")
+    strip = lambda d: {k: v for k, v in d.items() if k not in floats}
+    assert strip(new.summary()) == strip(ref.summary())
+    np.testing.assert_allclose(new.accuracy, ref.accuracy, atol=0.005)
+    np.testing.assert_allclose(new.val_loss, ref.val_loss, rtol=1e-5)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                                rtol=1e-5, atol=1e-6),
+        new_eng.params, ref_eng.params)
+
+
 # ---------------------------------------------------------------------------
 # chunked fast loop vs per-window host loop
 

@@ -6,8 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.agg.kernel import weighted_aggregate
-from repro.kernels.agg.ops import aggregate_params_tree, \
-    weighted_aggregate_tree
+from repro.kernels.agg.ops import aggregate_params_tree
 from repro.kernels.agg.ref import weighted_aggregate_ref
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
@@ -43,16 +42,12 @@ def test_agg_tree_paths(key):
             "b": {"c": jax.random.normal(jax.random.fold_in(key, 1),
                                          (5, 33))}}
     w = jnp.asarray([0.5, 0.2, 0.1, 0.1, 0.1])
-    got = weighted_aggregate_tree(tree, w, interpret=True)
-    ref = jax.tree.map(lambda u: jnp.tensordot(w, u, axes=1), tree)
+    params = jax.tree.map(lambda u: u[0], tree)
+    got = aggregate_params_tree(params, tree, w, interpret=True)
+    ref = jax.tree.map(lambda p, u: p + jnp.tensordot(w, u, axes=1),
+                       params, tree)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), atol=1e-5), got, ref)
-
-    params = jax.tree.map(lambda u: u[0], tree)
-    got2 = aggregate_params_tree(params, tree, w, interpret=True)
-    ref2 = jax.tree.map(lambda p, d: p + d, params, ref)
-    jax.tree.map(lambda a, b: np.testing.assert_allclose(
-        np.asarray(a), np.asarray(b), atol=1e-5), got2, ref2)
 
 
 # ---------------------------------------------------------------------------
