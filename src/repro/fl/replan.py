@@ -53,6 +53,7 @@ and its counts are in `docs/replanning.md` (Tracing).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -121,11 +122,36 @@ def _bucket(n: int) -> int:
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
 
 
-def _state_equal(a: SS.SatState, b: SS.SatState) -> bool:
-    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
-    return len(la) == len(lb) and all(
-        np.array_equal(np.asarray(x), np.asarray(y))
-        for x, y in zip(la, lb))
+@functools.partial(jax.jit, static_argnames=("columns", "s_max"))
+def _drift(states, conns, scalars, grants, needs, *, columns, s_max: int):
+    """The drift check as one program: realize the cached winner's bit
+    from the cached pre-state (`SS.step`), re-upload both that prediction
+    and the caller's state at the request's first window
+    (`SS.upload_step`), and return a device bool, True when the versions
+    or any column differ.
+
+    The inputs come packed, so a call moves three host arrays (five under
+    a link budget) to the device: `states` (2, len(columns), K) int32,
+    the cached pre-state and the caller's state by `SatState` column;
+    `conns` (2, K) bool, the cached plan's first window and the
+    request's; `scalars` (3,) int32, the cached version, the caller's and
+    the realized bit; `grants` (2, K) int32 and `needs` (2,) int32 (up,
+    down) of the two gates, or None. Only the column names and `s_max`
+    are static, so one trace serves every request of a K and gate or
+    none."""
+    pre, given = (SS.SatState(**dict(zip(columns, s))) for s in states)
+    pre_gate = gate = None
+    if grants is not None:
+        pre_gate = SS.LinkGate(grants[0], needs[0], needs[1])
+        gate = SS.LinkGate(grants[1], needs[0], needs[1])
+    after, g_after, _ = SS.step(pre, scalars[0], conns[0], scalars[2] != 0,
+                                s_max=s_max, collect="none", link=pre_gate)
+    predicted, _ = SS.upload_step(after, g_after, conns[1], gate)
+    rescanned, _ = SS.upload_step(given, scalars[1], conns[1], gate)
+    differs = g_after != scalars[1]
+    for x, y in zip(jax.tree.leaves(predicted), jax.tree.leaves(rescanned)):
+        differs = differs | jnp.any(x != y)
+    return differs
 
 
 def rollout_histograms(C: np.ndarray, *, s_max: int = 8,
@@ -281,9 +307,11 @@ class ReplanService:
         with tracing.span("replan.request", window=window):
             C_window = np.asarray(C_window, bool)
             self.maintain()
-            with tracing.span("replan.check", window=window):
+            with tracing.span("replan.check", window=window) as sp:
                 reason = self._delta_blocker(window, C_window, state, ig,
                                              status, link)
+                # the drift check is the blocker's last test
+                sp.set_metadata(drift_checked=int(reason in (None, "drift")))
             if reason is None:
                 self.last_mode, self.last_reason = "delta", None
                 self.stats["delta"] += 1
@@ -373,38 +401,37 @@ class ReplanService:
             return "narrowing"
         if np.count_nonzero(c.cands[:, 0] == c.winner_bit) < self.min_pool:
             return "pool"
-        if self._drifted(window, Cw, state, ig, link):
+        if self._drifted(Cw, state, ig, link):
             return "drift"
         return None
 
-    def _drifted(self, window, Cw, state, ig, link) -> bool:
+    def _drifted(self, Cw, state, ig, link) -> bool:
         """True when the caller's state is not the one the cached rollouts
-        predicted. The cached scan entered window `window` with the state
-        produced by realizing the winner's bit at window-1; a fresh rescan
-        would enter it by (idempotently) re-uploading the caller's
-        search-ready state. The two coincide — and every cached mark stays
-        valid — iff both post-upload states are equal, so that is the
-        check (one (K,)-sized transition each, exact integer compare)."""
+        predicted. The cached scan entered the request's first window
+        with the state produced by realizing the winner's bit at the
+        window before; a fresh rescan would enter it by (idempotently)
+        re-uploading the caller's search-ready state. The two coincide —
+        and every cached mark stays valid — iff both post-upload states
+        are equal, so that is the check: one compiled program (`_drift`),
+        exact int32 compares, one read of its bool. States that differ in
+        which optional columns they carry have drifted, decided here."""
         c = self._cache
-        prev_gate = self._gate(None if c.grant is None else c.grant[0],
-                               c.need_up, c.need_dn)
-        pre = jax.tree.map(jnp.asarray, c.pre_state)
-        after, g_after, _ = SS.step(
-            pre, jnp.int32(c.pre_ig), jnp.asarray(c.Cw[0]),
-            jnp.asarray(bool(c.winner_bit)), s_max=self.s_max,
-            collect="none", link=prev_gate)
-        if int(g_after) != int(ig):
+        columns = tuple(f for f in SS.SatState._fields
+                        if getattr(state, f) is not None)
+        if columns != tuple(f for f in SS.SatState._fields
+                            if getattr(c.pre_state, f) is not None):
             return True
-        gate0 = self._gate(None if link is None
-                           else np.asarray(link.grant, np.int32)[0],
-                           c.need_up, c.need_dn)
-        conn0 = jnp.asarray(Cw[0])
-        predicted, _ = SS.upload_step(after, g_after, conn0, gate0)
-        given = jax.tree.map(lambda x: jnp.asarray(np.asarray(x),
-                                                   jnp.int32), state)
-        rescanned, _ = SS.upload_step(given, jnp.int32(int(ig)), conn0,
-                                      gate0)
-        return not _state_equal(predicted, rescanned)
+        grants = needs = None
+        if c.grant is not None:
+            grants = np.stack([c.grant[0],
+                               np.asarray(link.grant, np.int32)[0]])
+            needs = np.array([c.need_up, c.need_dn], np.int32)
+        return bool(_drift(
+            np.array([[getattr(c.pre_state, f) for f in columns],
+                      [getattr(state, f) for f in columns]], np.int32),
+            np.stack([c.Cw[0], Cw[0]]),
+            np.array([c.pre_ig, ig, c.winner_bit], np.int32),
+            grants, needs, columns=columns, s_max=self.s_max))
 
     def _delta(self, window, Cw, state, ig, status, link):
         c = self._cache

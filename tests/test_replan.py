@@ -14,7 +14,7 @@ from repro.core.search import (random_candidates, scan_candidates,
 from repro.core.utility import (MLPRegressor, RandomForestRegressor,
                                 featurize, n_features, transfer_ready,
                                 transfer_report)
-from repro.fl.replan import (ReplanService, calibrate_forest,
+from repro.fl.replan import (ReplanService, _drift, calibrate_forest,
                              rollout_histograms)
 
 S_MAX = 8
@@ -175,6 +175,170 @@ def test_invalidation_drift():
     svc.replan(1, C[1:9], wrong_state, wrong_ig, 1.0,
                rng=np.random.default_rng(1))
     assert (svc.last_mode, svc.last_reason) == ("full", "drift")
+
+
+def _eager_drifted(svc, Cw, state, ig, link):
+    """The drift check as eager protocol steps and host compares of every
+    leaf: the reference the compiled check answers like."""
+    c = svc._cache
+
+    def gate(row):
+        return None if row is None else SS.LinkGate(
+            jnp.asarray(np.asarray(row), jnp.int32), jnp.int32(c.need_up),
+            jnp.int32(c.need_dn))
+
+    after, g_after, _ = SS.step(
+        jax.tree.map(jnp.asarray, c.pre_state), jnp.int32(c.pre_ig),
+        jnp.asarray(c.Cw[0]), jnp.asarray(bool(c.winner_bit)),
+        s_max=svc.s_max, collect="none",
+        link=gate(None if c.grant is None else c.grant[0]))
+    if int(g_after) != int(ig):
+        return True
+    gate0 = gate(None if link is None
+                 else np.asarray(link.grant, np.int32)[0])
+    conn0 = jnp.asarray(Cw[0])
+    predicted, _ = SS.upload_step(after, g_after, conn0, gate0)
+    given = jax.tree.map(lambda x: jnp.asarray(np.asarray(x), jnp.int32),
+                         state)
+    rescanned, _ = SS.upload_step(given, jnp.int32(int(ig)), conn0, gate0)
+    la, lb = jax.tree.leaves(predicted), jax.tree.leaves(rescanned)
+    return not (len(la) == len(lb) and all(
+        np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(la, lb)))
+
+
+def _gated_link(grant, i, need_up=2, need_dn=1):
+    return SS.LinkGate(jnp.asarray(grant[i:i + 8]), jnp.int32(need_up),
+                       jnp.int32(need_dn))
+
+
+def _absorbing_sat(C):
+    return int(np.flatnonzero(~C[0] & C[1])[0])
+
+
+def _primed_gated(K=24):
+    """`_primed` under a link budget (need_up 2 > 0, `progress` column)."""
+    rf = _forest()
+    C, state = _world(K=K)
+    grant = np.random.default_rng(4).integers(0, 3, (64, K)).astype(
+        np.int32)
+    # a satellite first in contact at window 1, granted a whole upload
+    # there: its (pending) upload completes whatever progress it holds
+    k = _absorbing_sat(C)
+    grant[0, k], grant[1, k] = 0, 2
+    state = SS.SatState(*state[:3], np.zeros(K, np.int32), None)
+    svc = ReplanService(rf, I0=8, num_candidates=64, s_max=S_MAX, seed=7,
+                        min_pool=4)
+    plan = svc.replan(0, C[0:8], state, 0, 1.0, link=_gated_link(grant, 0),
+                      rng=np.random.default_rng(0))
+    g0 = _gated_link(grant, 0)
+    st, g, _ = SS.step(jax.tree.map(jnp.asarray, state), jnp.int32(0),
+                       jnp.asarray(C[0]), jnp.asarray(bool(plan[0])),
+                       s_max=S_MAX, collect="none",
+                       link=SS.LinkGate(g0.grant[0], g0.need_up,
+                                        g0.need_dn))
+    return svc, C, jax.tree.map(np.asarray, st), int(g), grant
+
+
+def _drift_case(case):
+    """(service, Cw, state, ig, link, drifted) of one parity case."""
+    if case.startswith("gate"):
+        svc, C, state, ig, grant = _primed_gated()
+        if case != "gate":
+            # out of contact, a satellite keeps its progress through the
+            # upload, so the change shows; the absorbing satellite's
+            # upload completes and resets it, so the change is no drift
+            k = (int(np.flatnonzero(~C[1])[0]) if case == "gate, progress"
+                 else _absorbing_sat(C))
+            progress = state.progress.copy()
+            progress[k] += 1
+            state = state._replace(progress=progress)
+        return (svc, C[1:9], state, ig, _gated_link(grant, 1),
+                case == "gate, progress")
+    svc, C, state, ig, plan = _primed()
+    if case == "other action":
+        state, ig = _advance(state, 0, C[0], 1 - int(plan[0]))
+        # (aggregating an empty buffer would be no drift)
+        assert np.any(C[0] & (svc._cache.pre_state.pending >= 0))
+    elif case == "out-of-band aggregation":
+        ig = ig + 1
+    elif case == "pending changed":
+        pending = state.pending.copy()
+        pending[3] = pending[3] + 1 if pending[3] >= 0 else ig
+        state = state._replace(pending=pending)
+    elif case == "progress on one side":
+        state = state._replace(progress=np.zeros(C.shape[1], np.int32))
+    return svc, C[1:9], state, ig, None, case != "no drift"
+
+
+@pytest.mark.parametrize("case", [
+    "no drift", "other action", "out-of-band aggregation",
+    "pending changed", "gate", "gate, progress",
+    "gate, progress absorbed", "progress on one side"])
+def test_compiled_drift_check_matches_eager_steps(case):
+    """The compiled drift check answers as the eager protocol steps and
+    leaf compares it replaced, in each way a caller's state can drift or
+    not."""
+    svc, Cw, state, ig, link, drifted = _drift_case(case)
+    want = _eager_drifted(svc, Cw, state, ig, link)
+    assert svc._drifted(Cw, state, ig, link) == want == drifted
+    svc.replan(1, Cw, state, ig, 1.0, link=link,
+               rng=np.random.default_rng(1))
+    assert svc.last_reason == ("drift" if drifted else None)
+
+
+def test_drift_reasons_match_eager_check():
+    """A seeded stream with one injected drift (the caller realizes the
+    other action at window 3): the service answers and gives the same
+    reasons as one whose drift check is the eager reference."""
+    rf = _forest()
+    C, state0 = _world()
+
+    def drive(svc):
+        state, ig, out = state0, 0, []
+        for i in range(8):
+            plan = svc.replan(i, C[i:i + 8], state, ig, 1.0,
+                              rng=np.random.default_rng(100 + i))
+            out.append((svc.last_mode, svc.last_reason, plan))
+            bit = int(plan[0]) if i != 3 else 1 - int(plan[0])
+            state, ig = _advance(state, ig, C[i], bit)
+        return out
+
+    def service():
+        return ReplanService(rf, I0=8, num_candidates=256, s_max=S_MAX,
+                             seed=7, min_pool=8)
+
+    ref = service()
+    ref._drifted = lambda Cw, state, ig, link: _eager_drifted(
+        ref, Cw, state, ig, link)
+    got, want = drive(service()), drive(ref)
+    assert [(m, r) for m, r, _ in got] == [(m, r) for m, r, _ in want]
+    assert all(np.array_equal(a, b) for (_, _, a), (_, _, b)
+               in zip(got, want))
+    assert got[4][:2] == ("full", "drift")
+    assert [m for m, _, _ in got].count("delta") >= 4
+
+
+def test_drift_check_compiles_once_across_delta_answers():
+    """Ten consecutive delta answers, over which the versions and the
+    realized bits change, run the drift program from one trace: every
+    per-request value is an argument, none is static."""
+    rf = _forest(seed=1)
+    C, state = _world(p=0.5)
+    svc = ReplanService(rf, I0=8, num_candidates=2048, s_max=S_MAX, seed=7,
+                        min_pool=4)
+    _drift.clear_cache()
+    plan = svc.replan(0, C[0:8], state, 0, 1.0,
+                      rng=np.random.default_rng(0))
+    ig, bits, igs = 0, [], []
+    for i in range(1, 11):
+        bits.append(int(plan[0]))
+        state, ig = _advance(state, ig, C[i - 1], plan[0])
+        igs.append(ig)
+        plan = svc.replan(i, C[i:i + 8], state, ig, 1.0)
+        assert svc.last_mode == "delta", (i, svc.last_reason)
+    assert len(set(bits)) == 2 and len(set(igs)) >= 3
+    assert _drift._cache_size() == 1
 
 
 def test_invalidation_link_view():

@@ -132,6 +132,10 @@ def test_spans_nest_in_their_request(traced):
         inner = [s for s in spans if s is not req and request_of(s) is req]
         names = {s[0] for s in inner}
         assert "replan.check" in names and "replan.select" in names
+        check, = [s for s in inner if s[0] == "replan.check"]
+        # the drift program ran exactly where no earlier reason decided
+        assert check[4]["drift_checked"] == int(
+            mode == "delta" or reason == "drift")
         if mode == "delta":
             assert {"replan.delta", "replan.delta.extend",
                     "replan.delta.reduce"} <= names
