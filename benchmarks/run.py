@@ -1,19 +1,18 @@
-"""Benchmark driver — one entry per paper table/figure plus the kernel
-microbenchmarks and the roofline report. Prints ``name,us_per_call,derived``
+"""Benchmark driver for the paper reproductions: Fig. 2 (connectivity
+statistics), Table 2 (days to 40% top-1) and Fig. 7 (staleness and
+idleness, from the Table-2 runs). Prints ``name,us_per_call,derived``
 CSV rows (plus human-readable sections).
 
 Default mode is CPU-budget-friendly: Fig. 2 full-scale, Table 2 at a
 reduced horizon (sync capped; full runs live in results/table2.json via
-``python -m benchmarks.table2_training_time``), kernels in interpret mode,
-roofline from the recorded dry-run sweep.
+``python -m benchmarks.table2_training_time``). Speed is measured on the
+chip by ``bench/run.py``, not here.
 
     PYTHONPATH=src python -m benchmarks.run [--full]
 """
 from __future__ import annotations
 
 import argparse
-import json
-import os
 import time
 
 
@@ -59,45 +58,6 @@ def main() -> None:
     for r in rows:
         print(f"fig7_{r['scheme']},0,hist={r['staleness_hist']}"
               f"_idle={r['idle_connections']}of{r['total_connections']}")
-
-    # ------------------------------------------------ kernels
-    section("Kernel microbenchmarks (interpret mode; TPU is the target)")
-    from benchmarks.kernels_micro import rows as krows
-    for name, us, derived in krows():
-        print(f"{name},{us:.0f},{derived}")
-
-    # ------------------------------------------------ hot paths
-    section("Simulation hot paths (smoke shapes; committed full-shape "
-            "baseline in BENCH_hotpaths.json)")
-    from benchmarks.hotpaths import bench_aggregation, bench_search
-    s = bench_search(smoke=True)
-    print(f"hotpath_search_replan,{s['t_optimized_warm_s'] * 1e6:.0f},"
-          f"speedup={s['speedup_warm']:.1f}x"
-          f"_identical={s['schedule_identical']}")
-    a = bench_aggregation(smoke=True)
-    print(f"hotpath_aggregation,{a['t_batched_s'] * 1e6:.0f},"
-          f"speedup={a['speedup']:.1f}x_bit={a['params_bit_equal']}")
-
-    # ------------------------------------------------ roofline
-    section("Roofline (from the recorded dry-run sweep)")
-    path = os.path.join(os.path.dirname(__file__), "..", "results",
-                        "dryrun.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            drs = json.load(f)
-        ok = [r for r in drs if r["status"] == "ok"]
-        for r in sorted(ok, key=lambda r: (r["arch"], r["shape"])):
-            print(f"roofline_{r['arch']}_{r['shape']}_{r['mesh']},"
-                  f"{r['time_s'] * 1e6:.0f},"
-                  f"dom={r['dominant_term']}"
-                  f"_c={r['t_compute_s']:.2e}_m={r['t_memory_s']:.2e}"
-                  f"_x={r['t_collective_s']:.2e}")
-        doms = {}
-        for r in ok:
-            doms[r["dominant_term"]] = doms.get(r["dominant_term"], 0) + 1
-        print(f"roofline_summary,0,{doms}")
-    else:
-        print("roofline_missing,0,run repro.launch.sweep first")
 
     print(f"\n# total bench time: {time.time() - t0:.0f}s")
 

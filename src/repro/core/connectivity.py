@@ -311,8 +311,9 @@ class LinkBudget:
 
     Infinite capacity and zero latency (``gs_capacity=0`` and both needs 0)
     make `served == visible` and gate nothing — the engine and search then
-    reproduce the geometry-only trajectories bit-for-bit (the parity gate
-    in `benchmarks/hotpaths.py` enforces this).
+    reproduce the geometry-only trajectories bit-for-bit
+    (`tests/test_link_budget.py::test_search_trivial_gate_identical_schedule`
+    and `::test_link_budget_unlimited_is_geometry` enforce this).
     """
     visible: np.ndarray
     served: np.ndarray

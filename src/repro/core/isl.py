@@ -192,8 +192,9 @@ def identity_topology(K: int) -> ISLTopology:
     plane, every link a self-loop. Under it, sink election picks each
     satellite as its own sink and every relay arrives in place, so an
     ISL-enabled run must reproduce the plain ground-only protocol
-    bit-for-bit (the parity gate in `benchmarks/hotpaths.py` and
-    `tests/test_isl.py` runs exactly this)."""
+    bit-for-bit (the parity gate
+    `tests/test_isl.py::test_identity_topology_parity_with_fedbuff` runs
+    exactly this)."""
     idx = np.arange(K, dtype=np.int32)
     return ISLTopology(plane=idx.copy(), pos=np.zeros(K, np.int32),
                        nxt=idx.copy(), prv=idx.copy(), left=idx.copy(),

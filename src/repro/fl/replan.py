@@ -20,7 +20,8 @@ and scores only the delta:
   scheduled it — before re-reducing scores at the same (R, n_cap) shape a
   full rescan would use. Selection is therefore bit-identical to
   `score_candidates` + `select_candidate` on the same pool and state
-  (gated by the `replan` section of `benchmarks/hotpaths.py`).
+  (gated by
+  `tests/test_replan.py::test_delta_selection_bit_identical_to_full_rescan`).
 
 The cache is invalidated — the service falls back to a full rescan — on:
   * **drift**: the caller's state is not the one the cached rollouts

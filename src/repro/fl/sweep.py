@@ -18,8 +18,8 @@ sweep tracks the *protocol* trajectory (versions, staleness histograms,
 idleness — everything `SimResult` carries except accuracy): models are
 not trained, which is also what makes whole runs vmappable. Each
 variant's outcome is bit-identical to its sequential
-`SimulationEngine.run()` — the lockstep property tests and the
-`sweep_scaling` benchmark gate enforce it.
+`SimulationEngine.run()` — the lockstep property tests
+(`tests/test_sweep_and_mesh.py`) enforce it.
 
 What is sweepable: any engine whose scheduler `device_plan` is valid for
 the rest of the run (``horizon=None`` — sync/async/fedbuff/periodic/

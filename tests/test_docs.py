@@ -17,8 +17,7 @@ ANCHOR_RE = re.compile(r"`([\w./-]+\.py)::([\w.]+)`")
 # bare repo-relative paths inside backticks
 PATH_RE = re.compile(
     r"`((?:src|tests|benchmarks|examples|docs)/[\w./-]+\.\w+|"
-    r"(?:README|ROADMAP|PAPER|PAPERS|SNIPPETS|CHANGES)\.md|"
-    r"BENCH_hotpaths\.json)`")
+    r"(?:README|ROADMAP|PAPER|PAPERS|SNIPPETS|CHANGES)\.md)`")
 
 
 def _read(rel):

@@ -215,18 +215,29 @@ def test_featurize_jnp_matches_host():
     assert np.array_equal(dev[:, 9], host[:, 9])         # total count
 
 
-def test_fedspace_search_selects_identical_schedule():
+def _search_world(name, I0=24):
+    """A random K=24 world, or the first I0 windows of a constellation
+    preset's real geometry (a quarter day of propagated visibility)."""
+    if name == "random24":
+        return np.random.default_rng(5).random((I0, 24)) < 0.2, 512
+    C = CN.connectivity_sets(CN.constellation_preset(name), days=0.25)
+    return C[:I0], 256
+
+
+@pytest.mark.parametrize("world", ["random24", "starlink40", "starlink120",
+                                   "flock191", "starlink400",
+                                   "starlink1000"])
+def test_fedspace_search_selects_identical_schedule(world):
     """The acceptance gate: same rng seed => same selected schedule on the
-    device path as on the seed node-walk/host path."""
+    device path as on the seed node-walk/host path, on a random world and
+    on every constellation preset's geometry (K = 40 ... 1000)."""
     rf = _fit_hist_forest(0)
-    rng = np.random.default_rng(5)
-    K, I0 = 24, 24
-    C = rng.random((I0, K)) < 0.2
-    state = SS.bootstrap_state(K)
+    C, R = _search_world(world)
+    state = SS.bootstrap_state(C.shape[1])
     ref = fedspace_search(np.random.default_rng(7), C, state, 0,
-                          _NodeWalkHost(rf), 1.0, num_candidates=512)
+                          _NodeWalkHost(rf), 1.0, num_candidates=R)
     opt = fedspace_search(np.random.default_rng(7), C, state, 0, rf, 1.0,
-                          num_candidates=512)
+                          num_candidates=R)
     assert np.array_equal(ref, opt)
 
 

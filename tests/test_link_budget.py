@@ -4,7 +4,6 @@ the parity contracts — infinite capacity must equal raw geometry, and the
 schedule search must pick identical schedules under a trivial gate."""
 import numpy as np
 import jax.numpy as jnp
-import pytest
 
 from repro.core import connectivity as CN
 from repro.core import staleness as SS
@@ -275,20 +274,6 @@ def test_federation_builds_and_runs_link_budget():
     fed2 = Federation.from_experiment(exp2)
     assert fed2.link_budget is None
     assert fed2.engine().run().windows_run == 24
-
-
-def test_hotpaths_registers_all_sections_with_parity_gates():
-    """The benchmark runner iterates its section registry and fails on
-    omission — this pins the registry itself, so neither the link-budget
-    section nor any older parity gate can be dropped quietly."""
-    hp = pytest.importorskip("benchmarks.hotpaths")
-    expected = {"search_replan", "search_scaling", "aggregation_round",
-                "window_loop", "utility_sampler", "link_budget", "isl",
-                "faults", "sweep_scaling", "payloads", "replan"}
-    assert expected <= set(hp.SECTIONS)
-    for name in expected:
-        fn, parity = hp.SECTIONS[name]
-        assert callable(fn) and parity is not None, name
 
 
 def test_with_scheduler_shares_link_budget():

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import staleness as SS
+from repro.core.connectivity import connectivity_sets, constellation_preset
 from repro.core.search import (random_candidates, scan_candidates,
                                score_candidates, select_candidate)
 from repro.core.utility import (MLPRegressor, RandomForestRegressor,
@@ -60,15 +61,24 @@ def _advance(state, ig, conn, bit):
 # the tentpole invariant: delta == full rescan, bit for bit
 
 
+def _flock191_world(T=64):
+    """The first T windows of Planet Flock 191's propagated geometry."""
+    C = connectivity_sets(constellation_preset("flock191"), days=1.0)[:T]
+    return C, jax.tree.map(np.asarray, SS.bootstrap_state(C.shape[1]))
+
+
 @pytest.mark.parametrize("explicit_maintain", [True, False])
-def test_delta_selection_bit_identical_to_full_rescan(explicit_maintain):
+@pytest.mark.parametrize("world", ["random", "flock191"])
+def test_delta_selection_bit_identical_to_full_rescan(world,
+                                                      explicit_maintain):
     """Across a stream of consecutive replans, every answer — delta or
     full — must equal `score_candidates` + `select_candidate` on the
-    service's live pool from the caller's state. With
+    service's live pool from the caller's state, on a random K=24 world
+    and on flock191's real geometry (K=191). With
     `explicit_maintain=False` the service must fold the deferred frontier
     advance into the next answer itself."""
     rf = _forest()
-    C, state = _world()
+    C, state = _world() if world == "random" else _flock191_world()
     svc = ReplanService(rf, I0=8, num_candidates=64, s_max=S_MAX, seed=7,
                         min_pool=8)
     ig, status = 0, 3.0
