@@ -305,6 +305,16 @@ def forest_dense(regressor) -> int:
     return int(getattr(regressor, "dense", False))
 
 
+def device_predictor(regressor):
+    """(fn, arrays) with fn(*arrays, X) == regressor.predict_device(X), for
+    a caller that runs the prediction inside its own jitted program: the
+    regressor's own `device_predictor()` where it has one, else its bound
+    `predict_device` with no operands (a hashable static argument, whose
+    weights the caller's program then holds as constants)."""
+    own = getattr(regressor, "device_predictor", None)
+    return own() if own is not None else (regressor.predict_device, ())
+
+
 class RandomForestRegressor:
     def __init__(self, n_trees: int = 40, max_depth: int = 6,
                  min_leaf: int = 4, feature_frac: float = 0.8,
@@ -413,6 +423,14 @@ class RandomForestRegressor:
         (`_forest_predict_device`); deeper ones keep the gather traversal.
         The two give the same bits; they pick the same leaves as
         `predict`, whose numpy mean over trees may round differently."""
+        fn, arrays = self.device_predictor()
+        return fn(*arrays, jnp.asarray(X))
+
+    def device_predictor(self):
+        """`predict_device` as (fn, arrays), fn(*arrays, X): the walk and
+        the forest's device arrays, built once per fit. A program that
+        calls fn takes the arrays as operands, so the trees are not baked
+        into it and one compiled program serves every forest of a shape."""
         if self._device_arrays is None:
             fa = self.arrays()
             if self.dense:
@@ -426,8 +444,7 @@ class RandomForestRegressor:
                     jnp.asarray(a.reshape(-1))
                     for a in (fa.feature, fa.thresh, fa.left, fa.right,
                               fa.value)) + (jnp.asarray(offsets),))
-        fn, arrays = self._device_arrays
-        return fn(*arrays, jnp.asarray(X))
+        return self._device_arrays
 
 
 # ---------------------------------------------------------------------------

@@ -15,13 +15,32 @@ and scores only the delta:
   coincide with reality on the overlap, so every cached per-event utility
   over [j+1, j+I0) is *bit-identical* to what a fresh rescan would
   compute), extends each survivor with a drawn bit for the newly revealed
-  window j+I0, and simulates **only that window** — one vmapped
-  `repro.core.search.step_candidates` step over the candidates that
-  scheduled it — before re-reducing scores at the same (R, n_cap) shape a
-  full rescan would use. Selection is therefore bit-identical to
-  `score_candidates` + `select_candidate` on the same pool and state
-  (gated by
+  window j+I0, and simulates **only that window**. One compiled program
+  (`_delta_program`) does the device side: it gathers the survivors'
+  rows of the frontier, takes one vmapped
+  `repro.core.search.step_candidates` step (which is also the frontier's
+  advance), predicts the new window's utility where a survivor scheduled
+  it, slides `win_util` left by one window and sums each row. The marks and utilities are those a full
+  rescan computes; the scores sum the same per-event utilities over the
+  I0 axis instead of the event positions, so they equal
+  `score_candidates`' up to float summation order, inside
+  `select_candidate`'s 32-ulp tie band. Selection therefore equals
+  `score_candidates` + `select_candidate` on the same pool and state,
+  except where a candidate's score sits within a few ulp of that band's
+  edge (gated on seeded streams by
   `tests/test_replan.py::test_delta_selection_bit_identical_to_full_rescan`).
+
+The frontier (`end_state`, `end_ig`, `win_util`) has the full plan's R
+rows. A full plan leaves it on the host, and it moves to the device once:
+in `maintain`, which a caller runs between requests and which waits for
+the copy to land so that it stays off the next answer, or else in the
+first delta answer itself.
+From there the delta program's output stays on the device and nothing is
+read back. A caller that never answers by delta (a scheduler that
+replans at I0 strides) never moves it. Survivors are
+compacted to the front rows; the rows past them repeat a real row and are
+never read, so the program's shapes depend only on (R, K, the state
+dtype, gate or none) and it compiles once per service.
 
 The cache is invalidated — the service falls back to a full rescan — on:
   * **drift**: the caller's state is not the one the cached rollouts
@@ -63,12 +82,11 @@ import numpy as np
 
 from repro import tracing
 from repro.core import staleness as SS
-from repro.core.search import (event_positions, infer_n_range,
-                               random_candidates, scan_candidates,
-                               score_candidates, select_candidate,
-                               step_candidates)
-from repro.core.utility import (RandomForestRegressor, featurize,
-                                featurize_jnp, forest_dense,
+from repro.core.search import (infer_n_range, random_candidates,
+                               scan_candidates, score_candidates,
+                               select_candidate, step_candidates)
+from repro.core.utility import (RandomForestRegressor, device_predictor,
+                                featurize, featurize_jnp, forest_dense,
                                 transfer_ready)
 
 __all__ = ["ReplanService", "calibrate_forest", "rollout_histograms"]
@@ -81,13 +99,16 @@ class _Cache:
                  win_util, end_state, end_ig, state_dtype, pre_state,
                  pre_ig, winner_bit, status, density, n_max):
         self.window = window          # absolute window the plan answered
-        self.cands = cands            # (R, I0) int32 pool
+        self.cands = cands            # (S, I0) int32 pool, S <= R
         self.Cw = Cw                  # (I0, K) bool horizon view
         self.grant = grant            # (I0, K) int grants or None
         self.need_up = need_up
         self.need_dn = need_dn
+        # the frontier, R rows: host arrays as a full plan leaves them,
+        # device arrays once `maintain` or a delta answer has moved them;
+        # row r < S belongs to cands[r], the rows past S repeat a real row
         self.win_util = win_util      # (R, I0) f32 per-event utilities
-        self.end_state = end_state    # stacked SatState, frontier (host)
+        self.end_state = end_state    # stacked SatState
         self.end_ig = end_ig          # (R,) frontier versions
         self.state_dtype = state_dtype
         self.pre_state = pre_state    # int32 (K,) search state of the plan
@@ -96,7 +117,15 @@ class _Cache:
         self.status = status
         self.density = density        # pool aggregation density at draw
         self.n_max = n_max            # cap for extension bits
-        self.pending = None           # (conn, gate) of an unadvanced window
+
+    @property
+    def on_host(self) -> bool:
+        return isinstance(self.end_ig, np.ndarray)
+
+    def to_device(self):
+        """Move a full plan's host frontier onto the device."""
+        self.end_state, self.end_ig, self.win_util = jax.device_put(
+            (self.end_state, self.end_ig, self.win_util))
 
 
 def _np_state(state: SS.SatState) -> SS.SatState:
@@ -108,19 +137,62 @@ def _np_state(state: SS.SatState) -> SS.SatState:
                        else np.asarray(state.relay, np.int32))
 
 
-def _rows(state: SS.SatState, sel) -> SS.SatState:
-    """Index the leading (candidate) axis of a stacked SatState."""
-    return jax.tree.map(lambda x: x[sel], state)
-
-
 def _bucket(n: int) -> int:
-    """Next power-of-two batch bucket. The one-window `step_candidates`
-    kernel is jitted per batch shape, and the survivor pool decays across
-    delta steps — bucketing keeps the serving loop at a handful of
-    compiled shapes instead of one compile per request (which would dwarf
-    the <100 ms answer budget). Padded rows duplicate a real row and are
-    sliced off before use."""
+    """Next power of two at or above n: the survivor pool's bucket, which
+    the `replan.delta` span reports as its `bucket` count."""
     return 1 if n <= 1 else 1 << (n - 1).bit_length()
+
+
+def _pack(survivors, bits, conn, grant, needs, status, R: int):
+    """The delta program's one int32 input, laid out as `_unpack` reads
+    it: the survivors' frontier rows padded to R with row 0, their new
+    bits padded with 0, the revealed window's connectivity column, under
+    a link budget its grant row and (need_up, need_down), and last the
+    float32 status by its bits."""
+    pad = np.zeros(R - survivors.size, np.int64)
+    parts = [survivors, pad, bits, pad, conn]
+    if grant is not None:
+        parts += [grant, needs]
+    parts.append(np.float32(status).view(np.int32)[None])
+    return np.concatenate(parts).astype(np.int32)
+
+
+def _unpack(packed, R: int, K: int, gated: bool):
+    """(rows, bits, connected, link, status) of a `_pack`ed input."""
+    rows, bits = packed[:R], packed[R:2 * R]
+    connected = packed[2 * R:2 * R + K] != 0
+    link = None
+    if gated:
+        g = packed[2 * R + K:]
+        link = SS.LinkGate(g[:K], g[K], g[K + 1])
+    status = jax.lax.bitcast_convert_type(packed[-1], jnp.float32)
+    return rows, bits, connected, link, status
+
+
+@functools.partial(jax.jit, static_argnames=("predict", "s_max", "gated"))
+def _delta_program(states, igs, win_util, packed, forest, *, predict,
+                   s_max: int, gated: bool):
+    """A delta answer's device work as one program: gather the survivors'
+    frontier rows (`packed`, see `_pack`), step every row through the
+    revealed window (`step_candidates`), histogram, featurize and predict
+    (`predict(*forest, X)`, the regressor's `device_predictor`), keep the
+    utility where the row's new bit is 1 and 0 elsewhere, slide
+    `win_util` left by one window with that column last, and sum each
+    row. Returns the frontier advanced through the revealed window
+    (states, igs), the new `win_util` and the (R,) scores. Shapes are the frontier's R rows whatever the
+    survivor count, and every per-request value is an operand, so one
+    trace serves a service's every delta answer."""
+    R, K = igs.shape[0], states.version.shape[-1]
+    rows, bits, connected, link, status = _unpack(packed, R, K, gated)
+    states = jax.tree.map(lambda x: x[rows], states)
+    igs, win_util = igs[rows], win_util[rows]
+    marks, states, igs = step_candidates(states, igs, connected, bits,
+                                         link, s_max=s_max)
+    hists = SS.hist_from_marks(marks, s_max=s_max, dtype=jnp.int16)
+    util = predict(*forest, featurize_jnp(hists, status))
+    win_util = jnp.concatenate(
+        [win_util[:, 1:], jnp.where(bits == 1, util, 0.0)[:, None]], axis=1)
+    return states, igs, win_util, win_util.sum(axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("columns", "s_max"))
@@ -190,8 +262,9 @@ class ReplanService:
 
     One service holds one regressor (the forest-transfer handoff: fit
     once, serve any constellation) and the scan cache of its latest plan.
-    `replan` answers a request; `maintain` runs the deferred frontier
-    advance between requests so answer latency stays at delta cost.
+    `replan` answers a request; `maintain`, between requests, moves a
+    full plan's frontier to the device so that copy stays off the next
+    answer.
 
     Args:
       regressor: fitted utility model û; must pass
@@ -249,37 +322,22 @@ class ReplanService:
         """The current candidate pool (read-only copy), for parity gates."""
         return None if self._cache is None else self._cache.cands.copy()
 
-    def _gate(self, grant_row, need_up, need_dn):
-        if grant_row is None:
-            return None
-        return SS.LinkGate(jnp.asarray(np.asarray(grant_row), jnp.int32),
-                           jnp.int32(need_up), jnp.int32(need_dn))
-
     def maintain(self):
-        """Deferred delta-step bookkeeping: advance every cached frontier
-        state through the window revealed by the last delta replan. The
-        answer path only simulates the revealed window for candidates that
-        *scheduled* it; this advances the full pool so the next request is
-        again one-window work. Call it between requests (the server loop
-        does); a replan arriving first runs it inline, trading latency for
-        correctness."""
+        """Frontier upkeep between requests: move a full plan's host
+        frontier onto the device, so the next delta answer does not make
+        that copy itself, and wait until the copy has landed, so it does
+        not run on into the next answer's own transfers. A no-op once the
+        frontier is there (a delta answer leaves it there). The request
+        path never calls this, so a caller that only ever full-rescans
+        never moves it."""
         c = self._cache
-        if c is None or c.pending is None:
+        if c is None or not c.on_host:
             return
-        conn, gate = c.pending
-        S = int(c.end_ig.shape[0])
-        # `window` is the request this advance prepares: the next one
-        with tracing.span("replan.maintain", window=c.window + 1, rows=S,
-                          bucket=_bucket(S)):
-            sel = np.concatenate([np.arange(S),
-                                  np.zeros(_bucket(S) - S, np.int64)])
-            _, st, g = step_candidates(
-                jax.tree.map(jnp.asarray, _rows(c.end_state, sel)),
-                jnp.asarray(c.end_ig[sel]), jnp.asarray(conn),
-                jnp.asarray(c.cands[sel, -1]), gate, s_max=self.s_max)
-            c.end_state = jax.tree.map(lambda x: np.asarray(x)[:S], st)
-            c.end_ig = np.asarray(g)[:S]
-            c.pending = None
+        # `window` is the request this upkeep prepares: the next one
+        with tracing.span("replan.maintain", window=c.window + 1,
+                          rows=int(c.end_ig.shape[0])):
+            c.to_device()
+            jax.block_until_ready((c.end_state, c.end_ig, c.win_util))
 
     # -- request path -------------------------------------------------------
 
@@ -307,7 +365,6 @@ class ReplanService:
         """
         with tracing.span("replan.request", window=window):
             C_window = np.asarray(C_window, bool)
-            self.maintain()
             with tracing.span("replan.check", window=window) as sp:
                 reason = self._delta_blocker(window, C_window, state, ig,
                                              status, link)
@@ -438,6 +495,8 @@ class ReplanService:
         c = self._cache
         keep = c.cands[:, 0] == c.winner_bit
         S = int(np.count_nonzero(keep))
+        R = int(c.end_ig.shape[0])
+        gated = c.grant is not None
         with tracing.span("replan.delta", window=window, survivors=S,
                           bucket=_bucket(S)):
             with tracing.span("replan.delta.extend", window=window):
@@ -451,63 +510,33 @@ class ReplanService:
                     np.int32)
                 cands = np.concatenate([base[:, 1:], new_bits[:, None]],
                                        axis=1)
-                win_util = np.concatenate(
-                    [c.win_util[keep, 1:], np.zeros((S, 1), np.float32)],
-                    axis=1)
-                end_state = _rows(c.end_state, keep)
-                end_ig = c.end_ig[keep]
-                conn_new = Cw[-1]
-                gate_new = self._gate(
-                    None if link is None
+                packed = _pack(
+                    np.flatnonzero(keep), new_bits, Cw[-1],
+                    None if not gated
                     else np.asarray(link.grant, np.int32)[-1],
-                    c.need_up, c.need_dn)
-                rows1 = np.flatnonzero(new_bits == 1)
-            # simulate ONLY the newly revealed window, only for candidates
-            # that scheduled it — same marks→hist→featurize→predict
-            # pipeline as the full scan, from the cached frontier
-            if rows1.size:
-                m = rows1.size
-                with tracing.span("replan.delta.score", window=window,
-                                  scheduled=m, bucket=_bucket(m),
-                                  forest_dense=forest_dense(
-                                      self.regressor)):
-                    sel = np.concatenate(
-                        [rows1, np.full(_bucket(m) - m, rows1[0], np.int64)])
-                    marks, _, _ = step_candidates(
-                        jax.tree.map(jnp.asarray, _rows(end_state, sel)),
-                        jnp.asarray(end_ig[sel]), jnp.asarray(conn_new),
-                        jnp.asarray(new_bits[sel]), gate_new,
-                        s_max=self.s_max)
-                    hists = SS.hist_from_marks(marks, s_max=self.s_max,
-                                               dtype=jnp.int16)
-                    util = self.regressor.predict_device(
-                        featurize_jnp(hists, jnp.float32(status)))
-                    win_util[rows1, -1] = np.asarray(util)[:m]
-            # re-reduce at the same per-row (n_cap,) shape a full rescan
-            # would use, so the masked sum is bit-identical to
-            # score_candidates. Rows are bucket-padded with zeros (per-row
-            # sums unaffected) so the eager device reduction reuses a
-            # handful of compiled shapes instead of recompiling for every
-            # survivor count.
-            with tracing.span("replan.delta.reduce", window=window,
-                              rows=_bucket(S)) as sp:
-                idx, mask = event_positions(cands)
-                sp.set_metadata(width=idx.shape[1])
-                util_ev = np.take_along_axis(win_util, idx, axis=1)
-                pad = _bucket(S) - S
-                if pad:
-                    util_ev = np.concatenate(
-                        [util_ev,
-                         np.zeros((pad, util_ev.shape[1]), np.float32)])
-                    mask = np.concatenate(
-                        [mask, np.zeros((pad, mask.shape[1]), mask.dtype)])
-                scores = np.asarray((jnp.asarray(util_ev)
-                                     * jnp.asarray(mask, jnp.float32))
-                                    .sum(axis=1))[:S]
+                    (c.need_up, c.need_dn), status, R)
+            # simulate ONLY the newly revealed window, from the cached
+            # frontier: the full scan's marks -> hist -> featurize ->
+            # predict pipeline in one program, over the frontier's R rows;
+            # its step is also the frontier's advance
+            with tracing.span("replan.delta.score", window=window,
+                              scheduled=int(new_bits.sum()), rows=R,
+                              forest_dense=forest_dense(self.regressor)):
+                predict, forest = device_predictor(self.regressor)
+                packed = jnp.asarray(packed)
+                if c.on_host:         # no `maintain` since the full plan
+                    c.to_device()
+                end_state, end_ig, win_util, scores = _delta_program(
+                    c.end_state, c.end_ig, c.win_util, packed, forest,
+                    predict=predict, s_max=self.s_max, gated=gated)
+            # the wait for the program lands in this read
+            with tracing.span("replan.delta.reduce", window=window, rows=R,
+                              width=Cw.shape[0]):
+                scores = np.asarray(scores)[:S]
             with tracing.span("replan.select", window=window):
                 w = select_candidate(cands, scores)
-                # roll the cache forward; the frontier advance is deferred
-                # to maintain() so it stays off the answer path
+                # roll the cache forward, the frontier as the program
+                # left it: on the device, advanced through the window
                 c.window = window
                 c.cands = cands
                 c.Cw = Cw.copy()
@@ -519,5 +548,4 @@ class ReplanService:
                 c.pre_state = _np_state(state)
                 c.pre_ig = int(ig)
                 c.winner_bit = int(cands[w, 0])
-                c.pending = (conn_new.copy(), gate_new)
                 return cands[w].copy()

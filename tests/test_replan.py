@@ -1,6 +1,6 @@
 """Incremental replanning service (`repro.fl.replan`): the delta-window
-path must be *bit-identical* to a full rescan of the same pool from the
-caller's state, every invalidation rule must actually fire, and routing
+path must select what a full rescan of the same pool from the caller's
+state selects, every invalidation rule must actually fire, and routing
 the FedSpace scheduler through a service must not change a single
 trajectory bit."""
 import jax
@@ -10,12 +10,14 @@ import pytest
 
 from repro.core import staleness as SS
 from repro.core.connectivity import connectivity_sets, constellation_preset
-from repro.core.search import (random_candidates, scan_candidates,
-                               score_candidates, select_candidate)
+from repro.core.search import (event_positions, random_candidates,
+                               scan_candidates, score_candidates,
+                               select_candidate, step_candidates)
 from repro.core.utility import (MLPRegressor, RandomForestRegressor,
-                                featurize, n_features, transfer_ready,
-                                transfer_report)
-from repro.fl.replan import (ReplanService, _drift, calibrate_forest,
+                                featurize, featurize_jnp, n_features,
+                                transfer_ready, transfer_report)
+from repro.fl.replan import (ReplanService, _bucket,
+                             _delta_program, _drift, calibrate_forest,
                              rollout_histograms)
 
 S_MAX = 8
@@ -75,8 +77,12 @@ def test_delta_selection_bit_identical_to_full_rescan(world,
     full — must equal `score_candidates` + `select_candidate` on the
     service's live pool from the caller's state, on a random K=24 world
     and on flock191's real geometry (K=191). With
-    `explicit_maintain=False` the service must fold the deferred frontier
-    advance into the next answer itself."""
+    `explicit_maintain=False` the delta program takes the full plan's
+    frontier from the host itself. A delta answer's scores sum the same
+    per-event utilities as `score_candidates` in another order, so they
+    agree within `select_candidate`'s tie band and not bit for bit: the
+    selections are equal except for a candidate within a few ulp of the
+    band's edge, which these streams do not hold."""
     rf = _forest()
     C, state = _world() if world == "random" else _flock191_world()
     svc = ReplanService(rf, I0=8, num_candidates=64, s_max=S_MAX, seed=7,
@@ -351,6 +357,232 @@ def test_drift_check_compiles_once_across_delta_answers():
     assert _drift._cache_size() == 1
 
 
+# ---------------------------------------------------------------------------
+# the delta answer as one compiled program
+
+
+def _rows(state, sel):
+    return jax.tree.map(lambda x: x[sel], state)
+
+
+def _eager_delta(svc, window, Cw, state, ig, status, link):
+    """The delta answer as eager steps on a host-held frontier: survivor
+    rows copied on the host, the one-window step, histogram, features and
+    forest of the rows that scheduled the new window at a power-of-two
+    batch, and the event-position reduce a full rescan uses. The
+    reference the compiled program answers like."""
+    c = svc._cache
+    keep = c.cands[:, 0] == c.winner_bit
+    S = int(np.count_nonzero(keep))
+    base = c.cands[keep]
+    n_now = base[:, 1:].sum(axis=1)
+    draw = (svc._rng.random(S) < c.density).astype(np.int32)
+    new_bits = np.where(n_now < c.n_max, draw, 0).astype(np.int32)
+    cands = np.concatenate([base[:, 1:], new_bits[:, None]], axis=1)
+    win_util = np.concatenate(
+        [c.win_util[keep, 1:], np.zeros((S, 1), np.float32)], axis=1)
+    end_state = _rows(c.end_state, keep)
+    end_ig = c.end_ig[keep]
+    conn_new = Cw[-1]
+    gate_new = None if link is None else SS.LinkGate(
+        jnp.asarray(np.asarray(link.grant, np.int32)[-1]),
+        jnp.int32(c.need_up), jnp.int32(c.need_dn))
+    rows1 = np.flatnonzero(new_bits == 1)
+    if rows1.size:
+        m = rows1.size
+        sel = np.concatenate(
+            [rows1, np.full(_bucket(m) - m, rows1[0], np.int64)])
+        marks, _, _ = step_candidates(
+            jax.tree.map(jnp.asarray, _rows(end_state, sel)),
+            jnp.asarray(end_ig[sel]), jnp.asarray(conn_new),
+            jnp.asarray(new_bits[sel]), gate_new, s_max=svc.s_max)
+        hists = SS.hist_from_marks(marks, s_max=svc.s_max, dtype=jnp.int16)
+        util = svc.regressor.predict_device(
+            featurize_jnp(hists, jnp.float32(status)))
+        win_util[rows1, -1] = np.asarray(util)[:m]
+    idx, mask = event_positions(cands)
+    util_ev = np.take_along_axis(win_util, idx, axis=1)
+    pad = _bucket(S) - S
+    util_ev = np.concatenate([util_ev, np.zeros((pad, idx.shape[1]),
+                                                np.float32)])
+    mask = np.concatenate([mask, np.zeros((pad, idx.shape[1]), bool)])
+    scores = np.asarray((jnp.asarray(util_ev)
+                         * jnp.asarray(mask, jnp.float32)).sum(axis=1))[:S]
+    w = select_candidate(cands, scores)
+    c.window, c.cands, c.Cw = window, cands, Cw.copy()
+    if link is not None:
+        c.grant = np.asarray(link.grant, np.int32).copy()
+    c.win_util, c.end_state, c.end_ig = win_util, end_state, end_ig
+    c.pre_state = jax.tree.map(
+        lambda x: None if x is None else np.asarray(x, np.int32), state)
+    c.pre_ig, c.winner_bit = int(ig), int(cands[w, 0])
+    c.pending = (conn_new.copy(), gate_new)
+    return cands[w].copy()
+
+
+def _eager_maintain(svc):
+    """The host-held frontier's advance through the window the last
+    `_eager_delta` revealed (its `pending`), which the eager service ran
+    between requests or, failing that, before its next answer."""
+    c = svc._cache
+    if c is None or getattr(c, "pending", None) is None:
+        return
+    conn, gate = c.pending
+    S = int(c.end_ig.shape[0])
+    sel = np.concatenate([np.arange(S), np.zeros(_bucket(S) - S, np.int64)])
+    _, st, g = step_candidates(
+        jax.tree.map(jnp.asarray, _rows(c.end_state, sel)),
+        jnp.asarray(c.end_ig[sel]), jnp.asarray(conn),
+        jnp.asarray(c.cands[sel, -1]), gate, s_max=svc.s_max)
+    c.end_state = jax.tree.map(lambda x: np.asarray(x)[:S], st)
+    c.end_ig = np.asarray(g)[:S]
+    c.pending = None
+
+
+def _stream(world, gated, K=24):
+    """(C, state, grant or None) of a seeded request stream."""
+    C, state = _world(K=K) if world == "random" else _flock191_world()
+    if not gated:
+        return C, state, None
+    grant = np.random.default_rng(4).integers(0, 3, C.shape).astype(
+        np.int32)
+    state = SS.SatState(*state[:3], np.zeros(C.shape[1], np.int32), None)
+    return C, state, grant
+
+
+def _drive_stream(svc, C, state, grant, *, explicit_maintain, n=14):
+    """n consecutive requests, the status changing at request 7, each
+    answer's first action realized (under the grant when gated):
+    [(mode, reason, plan)]."""
+    ig, out = 0, []
+    for i in range(n):
+        link = None if grant is None else _gated_link(grant, i)
+        status = 3.0 if i < 7 else 2.5
+        plan = svc.replan(i, C[i:i + 8], state, ig, status, link=link,
+                          rng=np.random.default_rng(100 + i))
+        out.append((svc.last_mode, svc.last_reason, plan))
+        if explicit_maintain:
+            svc.maintain()
+        gate = None if link is None else SS.LinkGate(
+            link.grant[0], link.need_up, link.need_dn)
+        st, g, _ = SS.step(jax.tree.map(jnp.asarray, state), jnp.int32(ig),
+                           jnp.asarray(C[i]), jnp.asarray(bool(plan[0])),
+                           s_max=S_MAX, collect="none", link=gate)
+        state, ig = jax.tree.map(np.asarray, st), int(g)
+    return out
+
+
+@pytest.mark.parametrize("explicit_maintain", [True, False])
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("world", ["random", "flock191"])
+def test_delta_program_answers_as_eager_delta(world, gated,
+                                              explicit_maintain):
+    """A seeded stream of 14 requests: the service answers, in the same
+    modes and for the same reasons, as one whose delta answer and frontier
+    upkeep are the eager host-frontier reference."""
+    rf = _forest()
+    C, state, grant = _stream(world, gated)
+
+    def service():
+        return ReplanService(rf, I0=8, num_candidates=128, s_max=S_MAX,
+                             seed=7, min_pool=8)
+
+    def eager_answer(*a):
+        _eager_maintain(ref)
+        return _eager_delta(ref, *a)
+
+    ref = service()
+    ref._delta = eager_answer
+    ref.maintain = lambda: _eager_maintain(ref)
+    got, want = (_drive_stream(svc, C, state, grant,
+                               explicit_maintain=explicit_maintain)
+                 for svc in (service(), ref))
+    assert [(m, r) for m, r, _ in got] == [(m, r) for m, r, _ in want]
+    assert all(np.array_equal(a, b) for (_, _, a), (_, _, b)
+               in zip(got, want))
+    assert [m for m, _, _ in got].count("delta") >= 8
+
+
+def test_delta_program_compiles_once_across_buckets_and_widths():
+    """Delta answers whose survivor pool falls through several
+    power-of-two buckets, and whose event width (the pool's largest
+    aggregation count) changes with a second full plan's draw range, run
+    the delta program from one trace, whether `maintain` moved the
+    frontier to the device (the first plan) or the answer did (the
+    second)."""
+    rf = _forest(seed=1)
+    C, state = _world(p=0.5)
+    svc = ReplanService(rf, I0=8, num_candidates=2048, s_max=S_MAX, seed=7,
+                        min_pool=4)
+    _delta_program.clear_cache()
+    ig, plan, buckets, widths, modes = 0, None, set(), set(), []
+    for i in range(13):
+        if plan is not None:
+            state, ig = _advance(state, ig, C[i - 1], plan[0])
+        n = 2 if i < 7 else 4           # a status change at 7: full plan
+        plan = svc.replan(i, C[i:i + 8], state, ig, 1.0 if i < 7 else 2.0,
+                          rng=np.random.default_rng(i), n_min=n, n_max=n)
+        modes.append(svc.last_mode)
+        if svc.last_mode == "delta":
+            buckets.add(_bucket(len(svc.pool)))
+            widths.add(int(svc.pool.sum(axis=1).max()))
+        if i < 7:
+            svc.maintain()
+    assert modes == ["full"] + ["delta"] * 6 + ["full"] + ["delta"] * 5
+    assert len(buckets) >= 3 and len(widths) >= 2
+    assert _delta_program._cache_size() == 1
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_delta_answer_advances_the_device_frontier_as_step_candidates(
+        gated):
+    """`maintain` moves a full plan's frontier to the device unchanged,
+    and a delta answer leaves each survivor's frontier row on the device
+    and equal, read back, to `step_candidates` of its host copy through
+    the revealed window by its new bit."""
+    rf = _forest()
+    C, state, grant = _stream("random", gated)
+    svc = ReplanService(rf, I0=8, num_candidates=128, s_max=S_MAX, seed=7,
+                        min_pool=8)
+
+    def frontier():
+        c = svc._cache
+        return jax.tree.map(np.asarray, (c.end_state, c.end_ig))
+
+    link = None if grant is None else _gated_link(grant, 0)
+    plan = svc.replan(0, C[0:8], state, 0, 3.0, link=link,
+                      rng=np.random.default_rng(0))
+    host = frontier()
+    svc.maintain()
+    assert isinstance(svc._cache.end_ig, jax.Array)
+    assert jax.tree.all(jax.tree.map(np.array_equal, frontier(), host))
+    gate = None if link is None else SS.LinkGate(
+        link.grant[0], link.need_up, link.need_dn)
+    st, g, _ = SS.step(jax.tree.map(jnp.asarray, state), jnp.int32(0),
+                       jnp.asarray(C[0]), jnp.asarray(bool(plan[0])),
+                       s_max=S_MAX, collect="none", link=gate)
+    state, ig = jax.tree.map(np.asarray, st), int(g)
+    link = None if grant is None else _gated_link(grant, 1)
+    c = svc._cache
+    keep = np.flatnonzero(c.cands[:, 0] == c.winner_bit)
+    rows, igs = jax.tree.map(lambda x: x[keep], frontier())
+    svc.replan(1, C[1:9], state, ig, 3.0, link=link)
+    assert svc.last_mode == "delta"
+    S = len(svc.pool)
+    assert S == keep.size
+    gate = None if link is None else SS.LinkGate(
+        jnp.asarray(link.grant[-1]), link.need_up, link.need_dn)
+    _, want, want_ig = step_candidates(
+        jax.tree.map(jnp.asarray, rows), jnp.asarray(igs),
+        jnp.asarray(C[8]), jnp.asarray(svc.pool[:, -1]), gate,
+        s_max=S_MAX)
+    assert isinstance(svc._cache.end_ig, jax.Array)
+    got, got_ig = jax.tree.map(lambda x: x[:S], frontier())
+    assert np.array_equal(got_ig, np.asarray(want_ig))
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: np.array_equal(a, np.asarray(b)), got, want))
+
+
 def test_invalidation_link_view():
     svc, C, state, ig, _ = _primed()
     K = C.shape[1]
@@ -471,6 +703,56 @@ def test_fedspace_routed_through_service_is_bit_identical():
     assert routed.replan_stats is not None
     assert routed.replan_stats["full"] + routed.replan_stats["delta"] > 0
     assert routed.summary()["replan_stats"] == routed.replan_stats
+
+
+def test_maintain_returns_once_the_frontier_upload_has_landed(monkeypatch):
+    """`maintain` waits on the frontier it moved to the device before it
+    returns, so the copy does not run on into the next answer's own
+    transfers; with the frontier already there it waits on nothing."""
+    rf = _forest()
+    C, state, _ = _stream("random", False)
+    svc = ReplanService(rf, I0=8, num_candidates=128, s_max=S_MAX, seed=7,
+                        min_pool=8)
+    svc.replan(0, C[0:8], state, 0, 3.0, rng=np.random.default_rng(0))
+    waited, real = [], jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: real(waited.append(x) or x))
+    svc.maintain()
+    c = svc._cache
+    assert len(waited) == 1
+    leaves = jax.tree.leaves(waited[0])
+    assert [id(x) for x in leaves] == [
+        id(x) for x in jax.tree.leaves((c.end_state, c.end_ig, c.win_util))]
+    assert all(isinstance(x, jax.Array) and x.is_ready() for x in leaves)
+    svc.maintain()
+    assert len(waited) == 1
+
+
+def test_stride_routed_scheduler_never_moves_the_frontier(monkeypatch):
+    """The FedSpace scheduler replans at I0 strides, so a routed run's
+    every answer is a full rescan ('cold', then 'window'), and no request
+    uploads a frontier that the next full plan would throw away."""
+    from repro.core.scheduler import make_scheduler
+    from repro.fl.replan import _Cache
+    uploads = []
+    monkeypatch.setattr(_Cache, "to_device",
+                        lambda self: uploads.append(self.window))
+    rf = _forest()
+    C, state = _world()
+    I0 = 8
+    svc = ReplanService(rf, I0=I0, num_candidates=32, s_max=S_MAX, seed=0)
+    sched = make_scheduler("fedspace", regressor=rf, I0=I0, n_min=1,
+                           n_max=3, num_candidates=32, s_max=S_MAX,
+                           service=svc)
+    ig = 0
+    for i in range(3 * I0):
+        act = sched.decide(i, n_in_buffer=1, K=C.shape[1], state=state,
+                           ig=ig, connectivity=C, status=3.0)
+        state, ig = _advance(state, ig, C[i], act)
+    assert svc.stats["delta"] == 0 and svc.stats["full"] == 3
+    assert svc.stats["invalidated"] == {"window": 2}
+    assert uploads == []
+    assert isinstance(svc._cache.end_ig, np.ndarray)
 
 
 def test_scheduler_service_knob_mismatch_rejected():

@@ -13,15 +13,16 @@ import pytest
 from repro import tracing
 from repro.core import staleness as SS
 from repro.core.utility import RandomForestRegressor, featurize
-from repro.fl.replan import ReplanService
+from repro.fl.replan import ReplanService, _bucket
 
 S_MAX = 8
 I0 = 8
 # (window, status): a cold full rescan, deltas, a status change (full)
-# and deltas again; the service runs `maintain` explicitly once, after
-# request 3, and inline before every other request
+# and deltas again; the caller runs `maintain` once, after the cold plan,
+# so its frontier reaches the delta answers on the device, while the
+# status change's frontier reaches them from the host
 REQUESTS = [(i, 3.0) for i in range(5)] + [(i, 2.0) for i in range(5, 8)]
-MAINTAIN_AFTER = 3
+MAINTAIN_AFTER = 0
 
 
 def _forest():
@@ -34,7 +35,7 @@ def _forest():
 def _drive(svc):
     """Answer `REQUESTS` against one world, realizing each answer's first
     action; returns the plans and, per request, (mode, reason, pool
-    rows after it)."""
+    rows after it, pool rows that schedule its last window)."""
     rng = np.random.default_rng(1)
     C = rng.random((len(REQUESTS) + I0, 12)) < 0.4
     state = jax.tree.map(np.asarray, SS.bootstrap_state(12))
@@ -43,7 +44,8 @@ def _drive(svc):
         plan = svc.replan(window, C[window:window + I0], state, ig, status,
                           rng=np.random.default_rng(100 + window))
         plans.append(plan)
-        seen.append((svc.last_mode, svc.last_reason, len(svc.pool)))
+        seen.append((svc.last_mode, svc.last_reason, len(svc.pool),
+                     int(svc.pool[:, -1].sum())))
         st, g, _ = SS.step(jax.tree.map(jnp.asarray, state),
                            jnp.int32(ig), jnp.asarray(C[window]),
                            jnp.asarray(bool(plan[0])), s_max=S_MAX,
@@ -128,7 +130,7 @@ def test_spans_nest_in_their_request(traced):
     outside = [s for s in spans if s[0] == "replan.maintain"
                and request_of(s) is None]
     assert len(outside) == 1
-    for (mode, reason, rows), req in zip(seen, requests):
+    for (mode, reason, rows, scheduled), req in zip(seen, requests):
         inner = [s for s in spans if s is not req and request_of(s) is req]
         names = {s[0] for s in inner}
         assert "replan.check" in names and "replan.select" in names
@@ -138,17 +140,19 @@ def test_spans_nest_in_their_request(traced):
             mode == "delta" or reason == "drift")
         if mode == "delta":
             assert {"replan.delta", "replan.delta.extend",
-                    "replan.delta.reduce"} <= names
+                    "replan.delta.score", "replan.delta.reduce"} <= names
             assert not names & {"replan.full", "search.scan"}
             delta, = [s for s in inner if s[0] == "replan.delta"]
             assert delta[4]["survivors"] == rows
-            assert delta[4]["bucket"] >= rows
+            assert delta[4]["bucket"] == _bucket(rows) >= rows
+            # the program runs at the full plan's pool of rows
+            score, = [s for s in inner if s[0] == "replan.delta.score"]
+            assert score[4]["rows"] == svc.num_candidates >= rows
+            assert score[4]["scheduled"] == scheduled <= rows
+            assert score[4]["forest_dense"] == 1
             reduce_, = [s for s in inner if s[0] == "replan.delta.reduce"]
-            assert reduce_[4]["rows"] == delta[4]["bucket"]
-            assert 1 <= reduce_[4]["width"] <= I0
-            for score in (s for s in inner if s[0] == "replan.delta.score"):
-                assert 1 <= score[4]["scheduled"] <= score[4]["bucket"]
-                assert score[4]["forest_dense"] == 1
+            assert reduce_[4]["rows"] == score[4]["rows"]
+            assert reduce_[4]["width"] == I0
         else:
             assert {"replan.full", "replan.full.draw", "search.scan",
                     "search.chunk", "search.fetch"} <= names
@@ -158,7 +162,7 @@ def test_spans_nest_in_their_request(traced):
             scan, = [s for s in inner if s[0] == "search.scan"]
             assert scan[4] == {"rows": 64, "chunks": 1, "forest_dense": 1}
     assert any(s[0] == "replan.delta.score" for s in spans)
-    assert [m for m, _, _ in seen].count("full") == 2
+    assert [m for m, _, _, _ in seen].count("full") == 2
     assert seen[0][1] == "cold" and seen[5][1] == "status"
     assert svc.last_reason == seen[-1][1]
 
